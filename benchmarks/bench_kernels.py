@@ -250,8 +250,7 @@ def rows(names: Optional[Iterable[str]] = None,
                     continue
                 recs = records_for(op, mesh=mesh, real=real)
                 if json_dir:
-                    env = bench_env(interpret=True,
-                                    hw_model=DEFAULT_DISPATCHER.hw.name)
+                    env = bench_env(hw_model=DEFAULT_DISPATCHER.hw.name)
                     if mesh > 1:
                         env["mesh_shape"] = [mesh]
                         env["mesh_exec_mode"] = ("mesh" if real

@@ -72,15 +72,24 @@ def emit(rows: List[dict], out: Optional[TextIO] = None) -> None:
                          r.get("derived", "")])
 
 
-def bench_env(interpret: bool = True, hw_model: str = "") -> dict:
-    """Environment metadata recorded alongside every schema-2 record set."""
+def bench_env(hw_model: str = "") -> dict:
+    """Environment metadata recorded alongside every schema-2 record set.
+
+    ``interpret`` is the decision the Pallas launches of this process
+    take (:func:`repro.core.dispatch.use_interpreter`), so a chip run is
+    never labelled as interpreted.
+    """
     import numpy
 
+    from repro.core.dispatch import use_interpreter
+
+    device = jax.devices()[0]
     return {
         "jax": jax.__version__,
         "numpy": numpy.__version__,
-        "device": jax.devices()[0].platform,
-        "interpret": bool(interpret),
+        "device": device.platform,
+        "device_kind": device.device_kind,
+        "interpret": use_interpreter(),
         "hw_model": hw_model,
     }
 

@@ -95,6 +95,8 @@ def _take_flag(argv: List[str], flag: str, what: str) -> Optional[str]:
 
 def main(argv: Optional[List[str]] = None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if argv and argv[0] == "tune":
         # the tuner has its own argparse surface (budget, out, ...)
         from . import tune

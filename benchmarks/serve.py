@@ -199,7 +199,7 @@ def _serve_lm(args: argparse.Namespace) -> int:
     policy = BatchPolicy(max_batch=args.max_batch,
                          max_wait_s=args.max_wait_ms / 1e3)
     slo = SLO(latency_ms=args.slo_ms)
-    env = bench_env(interpret=True, hw_model=DEFAULT_DISPATCHER.hw.name)
+    env = bench_env(hw_model=DEFAULT_DISPATCHER.hw.name)
     print("kernel,engine,workload,completed,p50_ms,p99_ms,goodput_rps,"
           "slo_attainment")
     for name in configs:
@@ -358,7 +358,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     policy = BatchPolicy(max_batch=args.max_batch,
                          max_wait_s=args.max_wait_ms / 1e3)
     slo = SLO(latency_ms=args.slo_ms)
-    env = bench_env(interpret=True, hw_model=DEFAULT_DISPATCHER.hw.name)
+    env = bench_env(hw_model=DEFAULT_DISPATCHER.hw.name)
     if args.mesh > 1:
         env["mesh_shape"] = [args.mesh]
         env["mesh_exec_mode"] = "mesh" if args.real else "virtual"

@@ -4,7 +4,7 @@ Usage::
 
     python -m benchmarks.run tune [--kernel K] [--budget N]
         [--out tuned.json] [--size N] [--dtype D] [--seed N]
-        [--time-pallas] [--no-interpret]
+        [--time-pallas]
 
 Per (kernel family, engine, dtype) the tuner enumerates the family's
 declared ``tile_space`` (capped at ``--budget`` candidates, static
@@ -17,10 +17,10 @@ so repeated partial runs accumulate.
 Timing defaults to each family's pure-XLA proxy
 (``repro.tuning.proxy``): real compiled wall time whose tile
 sensitivity mirrors the grid launch.  ``--time-pallas`` times the
-actual Pallas entry points instead — only valid with
-``--no-interpret`` on real hardware; with interpret mode the cache
-refuses to persist (interpret wall times measure the emulator, and a
-tile choice laundered from them would be noise).
+actual Pallas entry points instead — only valid on a TPU backend, where
+the kernels run compiled; elsewhere they would run in the interpreter,
+whose wall times measure the emulator (the cache refuses to persist
+them: a tile choice laundered from them would be noise).
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ import os
 import sys
 from typing import List, Optional
 
-from repro.core.dispatch import DEFAULT_DISPATCHER
+from repro.core.dispatch import DEFAULT_DISPATCHER, use_interpreter
 from repro.kernels import registry
 from repro.tuning import (InterpretTimingError, TuningCache,
                           env_fingerprint, tune_op)
@@ -72,22 +72,19 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="input-builder RNG seed (default 0)")
     p.add_argument("--time-pallas", action="store_true",
                    help="time the real Pallas kernels instead of the "
-                        "pure-XLA proxies (requires --no-interpret on "
-                        "real hardware)")
-    p.add_argument("--no-interpret", action="store_true",
-                   help="run Pallas with interpret=False (real TPU only)")
+                        "pure-XLA proxies (TPU backend only)")
     args = p.parse_args(argv)
 
-    if args.time_pallas and not args.no_interpret:
+    if args.time_pallas and use_interpreter():
         # statically invalid: interpret-mode Pallas wall times measure
         # the emulator, and the cache would refuse them anyway -- fail
         # before burning minutes timing candidates
         raise SystemExit(
-            "error: --time-pallas requires --no-interpret (real "
-            "hardware): interpret-mode Pallas wall times measure the "
-            "emulator's Python loop, and tile choices based on them "
-            "are refused at persist. Drop --time-pallas to use the "
-            "pure-XLA proxies instead.")
+            "error: --time-pallas needs a TPU backend: off the chip the "
+            "kernels run in the interpreter, interpret-mode wall times "
+            "measure the emulator's Python loop, and tile choices based "
+            "on them are refused at persist. Drop --time-pallas to use "
+            "the pure-XLA proxies instead.")
 
     if args.kernel is not None:
         try:
@@ -99,7 +96,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     hw_model = DEFAULT_DISPATCHER.hw.name
     source = "pallas" if args.time_pallas else "proxy"
-    interpret = not args.no_interpret
     # fresh results carry the environment they were timed in, so a
     # merge into an older file re-stamps the fingerprint correctly
     cache = TuningCache(fingerprint=env_fingerprint())
@@ -113,8 +109,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             for dtype in dtypes:
                 entry = tune_op(
                     op, engine=engine, dtype=dtype, size=args.size,
-                    budget=args.budget, source=source,
-                    interpret=interpret, hw_model=hw_model,
+                    budget=args.budget, source=source, hw_model=hw_model,
                     seed=args.seed)
                 if entry is None:
                     continue

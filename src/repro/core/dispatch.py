@@ -20,6 +20,9 @@ flatten/pad/tile/unpad plumbing around ``pallas_call``, and (3) the
     block-spec construction for same-shape elementwise kernels (SCALE,
     STREAM Triad, AXPY, ...): a kernel family supplies only its per-tile
     Pallas bodies.
+  * ``use_interpreter`` -- the one interpret-vs-compiled decision every
+    Pallas launch resolves its ``interpret=None`` default through:
+    compiled on a TPU backend, the Pallas interpreter elsewhere.
 
 Kernel families register their bodies as an ``EngineOp`` in
 ``repro.kernels.registry``; ``DEFAULT_DISPATCHER.run`` is the single
@@ -47,7 +50,8 @@ from .intensity import KernelTraits
 
 __all__ = [
     "DEFAULT_DISPATCHER", "Dispatcher", "TUNED_CACHE_ENV", "TuningPolicy",
-    "default_cache_key", "elementwise_call", "normalize_engine",
+    "default_cache_key", "elementwise_call", "mxu_dot", "normalize_engine",
+    "use_interpreter",
     "ELEMENTWISE_BLOCK_ROWS", "ELEMENTWISE_LANES",
 ]
 
@@ -58,6 +62,32 @@ _ENGINE_ALIASES = {
     "mxu": "matrix", "matrix": "matrix",
     "vpu": "vector", "vector": "vector",
 }
+
+
+def use_interpreter(interpret: Optional[bool] = None) -> bool:
+    """The one interpret-vs-compiled decision for every Pallas launch.
+
+    ``None`` -- the default everywhere on the main path -- takes it from
+    the backend: compiled Mosaic kernels on a TPU, the Pallas interpreter
+    on any other backend.  An explicit bool is honoured as given (a test
+    may force the interpreter).
+    """
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def mxu_dot(a, b):
+    """``a @ b`` on the matrix engine, accumulated in f32.
+
+    f32 operands contract at HIGHEST precision (the TPU default would
+    round them to bf16), so the matrix-engine variants compute the same
+    numbers as their vector twins; bf16 operands are native MXU inputs.
+    """
+    f32 = jnp.float32 in (a.dtype, b.dtype)
+    return jax.lax.dot(a, b,
+                       precision=jax.lax.Precision.HIGHEST if f32 else None,
+                       preferred_element_type=jnp.float32)
 
 
 def normalize_engine(engine: str) -> Optional[str]:
@@ -339,7 +369,8 @@ class Dispatcher:
                                    num_shards=self._mesh_shards)
         return dict(entry.params) if entry is not None else None
 
-    def run(self, op, *args, engine: str = "auto", interpret: bool = True,
+    def run(self, op, *args, engine: str = "auto",
+            interpret: Optional[bool] = None,
             tile_config: Optional[Mapping[str, int]] = None, **kwargs):
         """Advisor-route (paper §6), tile-tune, and launch one op.
 
@@ -367,7 +398,7 @@ class Dispatcher:
                              tile_config=tile_config,
                              _span_attrs=span_attrs, **kwargs)
 
-    def _run(self, op, *args, engine: str, interpret: bool,
+    def _run(self, op, *args, engine: str, interpret: Optional[bool],
              tile_config: Optional[Mapping[str, int]],
              _span_attrs: Optional[Dict[str, Any]] = None, **kwargs):
         # tile params never move a kernel on the roofline: strip them
@@ -489,7 +520,8 @@ def _elementwise_grid(body, scalars, arrays, *, block_rows: int,
 
 
 def elementwise_call(body: Callable, arrays: Sequence[jnp.ndarray],
-                     scalars: Sequence[Any] = (), *, interpret: bool = True,
+                     scalars: Sequence[Any] = (), *,
+                     interpret: Optional[bool] = None,
                      lanes: Optional[int] = None,
                      block_rows: Optional[int] = None) -> jnp.ndarray:
     """Run an elementwise Pallas body over same-shape arrays of any shape.
@@ -526,5 +558,6 @@ def elementwise_call(body: Callable, arrays: Sequence[jnp.ndarray],
     scalars2d = tuple(jnp.asarray(s, jnp.float32).reshape(1, 1)
                       for s in scalars)
     out = _elementwise_grid(body, scalars2d, tuple(flats),
-                            block_rows=block_rows, interpret=interpret)
+                            block_rows=block_rows,
+                            interpret=use_interpreter(interpret))
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)

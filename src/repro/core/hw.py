@@ -77,10 +77,11 @@ GH200 = HardwareSpec(
 )
 
 # --- TPU target ------------------------------------------------------------
-# v5e constants fixed by the assignment: 197 TFLOP/s bf16 (MXU), 819 GB/s HBM,
-# ~50 GB/s per ICI link.  The VPU peak is derived from the published unit
-# shape: 8 lanes x 128 sublanes x 2 FLOP (FMA) x 4 units x ~0.94 GHz
-# ~= 7.7e12 f32 FLOP/s; we round to 7.5 TF and record it as an estimate.
+# v5e peaks from Google Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16
+# (MXU), 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of interconnect per chip
+# (~50 GB/s per ICI link).  The VPU peak is not published; it is derived
+# from the unit shape: 8 lanes x 128 sublanes x 2 FLOP (FMA) x 4 units x
+# ~0.94 GHz ~= 7.7e12 f32 FLOP/s, rounded to 7.5 TF and kept as an estimate.
 TPU_V5E = HardwareSpec(
     name="TPU-v5e",
     mem_bw=819e9,
@@ -97,6 +98,23 @@ PLATFORMS: Dict[str, HardwareSpec] = {
     "gh200": GH200,
     "v5e": TPU_V5E,
 }
+
+
+#: Specs by the ``device_kind`` JAX reports for a device
+#: (``jax.devices()[0].device_kind``).
+DEVICE_KINDS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def spec_for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of a device JAX reports; a kind not in the table is an
+    error, never a default."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no hardware spec for device_kind {kind!r}; "
+                       f"known kinds: {sorted(DEVICE_KINDS)}") from None
 
 
 def get_platform(name: str) -> HardwareSpec:

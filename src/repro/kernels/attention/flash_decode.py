@@ -14,11 +14,14 @@ plus small VMEM scratch for (m, l).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...core.dispatch import mxu_dot, use_interpreter
 
 NEG_INF = -1e30
 
@@ -40,7 +43,7 @@ def _flash_decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
 
     if engine == "matrix":
-        s = jax.lax.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = mxu_dot(q, k.T) * scale
     else:  # vector engine: broadcast-multiply + lane reduction, no MXU
         s = jnp.sum(q[:, None, :] * k[None, :, :], axis=-1) * scale
     pos = j * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -52,7 +55,7 @@ def _flash_decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
     if engine == "matrix":
-        pv = jax.lax.dot(p, v, preferred_element_type=jnp.float32)
+        pv = mxu_dot(p, v)
     else:
         pv = jnp.sum(p[:, :, None] * v[None, :, :], axis=1)
     acc_ref[...] = acc_ref[...] * corr + pv
@@ -69,7 +72,7 @@ def _flash_decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref,
                    static_argnames=("block_s", "engine", "interpret"))
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  kv_len, *, block_s: int = 512, engine: str = "matrix",
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, KH, G, Dh); k,v: (B, S, KH, Dh); kv_len scalar int32.
 
     ``engine`` picks the per-block compute: 'matrix' drives the MXU with
@@ -106,6 +109,6 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           engine=engine),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * kh, g, dh), q.dtype),
-        interpret=interpret,
+        interpret=use_interpreter(interpret),
     )(kvl, qf, kf, vf)
     return out.reshape(b, kh, g, dh)

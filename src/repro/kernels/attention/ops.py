@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +52,8 @@ def _clamp_block_s(s: int, block_s) -> int:
 
 
 def _engine_fn(engine: str):
-    def call(q, k, v, kv_len, *, block_s=None, interpret: bool = True):
+    def call(q, k, v, kv_len, *, block_s=None,
+             interpret: Optional[bool] = None):
         if block_s is None:
             block_s = DEFAULT_BLOCK_S
         bs = _clamp_block_s(k.shape[1], block_s)
@@ -135,7 +137,7 @@ ATTENTION_OP = register(EngineOp(
 
 
 def decode_attention(q, k, v, kv_len, *, engine: str = "auto",
-                     block_s: int = None, interpret: bool = True):
+                     block_s: int = None, interpret: Optional[bool] = None):
     """Single-token GQA attention against a KV cache.
 
     Intensity ~= (4 flops per cache element) / (2 bytes per element) --

@@ -11,10 +11,11 @@ All padding/tiling comes from the shared dispatch-layer wrapper.
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
-from ...core.dispatch import elementwise_call
+from ...core.dispatch import elementwise_call, mxu_dot
 
 
 def _axpy_vpu_kernel(a_ref, x_ref, y_ref, o_ref):
@@ -26,13 +27,12 @@ def _axpy_mxu_kernel(a_ref, x_ref, y_ref, o_ref):
     eye = jnp.eye(bn, dtype=x_ref.dtype)
     ai = (a_ref[0, 0] * eye).astype(x_ref.dtype)
     o_ref[...] = (
-        jax.lax.dot(x_ref[...], ai, preferred_element_type=jnp.float32)
-        + jax.lax.dot(y_ref[...], eye, preferred_element_type=jnp.float32)
+        mxu_dot(x_ref[...], ai) + mxu_dot(y_ref[...], eye)
     ).astype(o_ref.dtype)
 
 
 def axpy_vector(a, x: jnp.ndarray, y: jnp.ndarray, *,
-                interpret: bool = True, block_rows: int = None,
+                interpret: Optional[bool] = None, block_rows: int = None,
                 lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_axpy_vpu_kernel, (x, y), (a,),
                             interpret=interpret, block_rows=block_rows,
@@ -40,7 +40,7 @@ def axpy_vector(a, x: jnp.ndarray, y: jnp.ndarray, *,
 
 
 def axpy_matrix(a, x: jnp.ndarray, y: jnp.ndarray, *,
-                interpret: bool = True, block_rows: int = None,
+                interpret: Optional[bool] = None, block_rows: int = None,
                 lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_axpy_mxu_kernel, (x, y), (a,),
                             interpret=interpret, block_rows=block_rows,

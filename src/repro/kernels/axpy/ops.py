@@ -1,6 +1,8 @@
 """Public AXPY op, registered as an ``EngineOp``."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,6 +53,6 @@ AXPY_OP = register(EngineOp(
 
 
 def axpy(a, x: jnp.ndarray, y: jnp.ndarray, *, engine: str = "auto",
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: Optional[bool] = None) -> jnp.ndarray:
     """y = a * x + y for arbitrary same-shaped x, y."""
     return AXPY_OP(a, x, y, engine=engine, interpret=interpret)

@@ -73,7 +73,8 @@ class EngineOp:
     # paper Eq. 13); None = no halo
     shard_halo: Optional[Callable[..., int]] = None
 
-    def __call__(self, *args, engine: str = "auto", interpret: bool = True,
+    def __call__(self, *args, engine: str = "auto",
+                 interpret: Optional[bool] = None,
                  tile_config: Optional[Mapping[str, int]] = None,
                  **kwargs):
         """Launch via the default dispatcher ('auto' = paper §6 routing).

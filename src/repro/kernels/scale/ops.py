@@ -1,6 +1,8 @@
 """Public SCALE op, registered as an ``EngineOp`` (paper Fig. 6)."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,7 +52,7 @@ SCALE_OP = register(EngineOp(
 
 
 def scale(b: jnp.ndarray, q, *, engine: str = "auto",
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: Optional[bool] = None) -> jnp.ndarray:
     """a = q * b for arbitrary-shaped b.
 
     engine: 'auto' (paper §6 advisor -> VPU, since I=1/(2D) is far below

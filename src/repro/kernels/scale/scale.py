@@ -16,11 +16,12 @@ the per-tile bodies plus their engine entry points.
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
 from ...core.dispatch import (ELEMENTWISE_BLOCK_ROWS, ELEMENTWISE_LANES,
-                              elementwise_call)
+                              elementwise_call, mxu_dot)
 
 # retained names: the (rows, 1024)-wide layout both engines share
 LANES = ELEMENTWISE_LANES
@@ -35,19 +36,17 @@ def _scale_mxu_kernel(q_ref, b_ref, o_ref):
     bn = b_ref.shape[-1]
     eye = jnp.eye(bn, dtype=b_ref.dtype)
     qi = (q_ref[0, 0] * eye).astype(b_ref.dtype)  # q * I, built in VMEM
-    o_ref[...] = jax.lax.dot(
-        b_ref[...], qi,
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    o_ref[...] = mxu_dot(b_ref[...], qi).astype(o_ref.dtype)
 
 
-def scale_vector(b: jnp.ndarray, q, *, interpret: bool = True,
+def scale_vector(b: jnp.ndarray, q, *, interpret: Optional[bool] = None,
                  block_rows: int = None, lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_scale_vpu_kernel, (b,), (q,),
                             interpret=interpret, block_rows=block_rows,
                             lanes=lanes)
 
 
-def scale_matrix(b: jnp.ndarray, q, *, interpret: bool = True,
+def scale_matrix(b: jnp.ndarray, q, *, interpret: Optional[bool] = None,
                  block_rows: int = None, lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_scale_mxu_kernel, (b,), (q,),
                             interpret=interpret, block_rows=block_rows,

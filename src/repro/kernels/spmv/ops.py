@@ -10,6 +10,7 @@ retiling is done where the layout is built.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -61,7 +62,7 @@ SPMV_OP = register(EngineOp(
 
 
 def spmv(bell: BlockEll, x: jnp.ndarray, *, engine: str = "auto",
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: Optional[bool] = None) -> jnp.ndarray:
     """y = A x, A in block-ELL.
 
     'auto' consults the paper's advisor with the format's true traits;

@@ -19,12 +19,14 @@ axis (revisited output block, initialized at j == 0).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.dispatch import use_interpreter
 from .ref import BlockEll
 
 
@@ -37,8 +39,8 @@ def _spmv_vpu_kernel(cols_ref, blocks_ref, x_ref, y_ref):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     a = blocks_ref[0, 0]          # (bm, bn)
-    xb = x_ref[...]               # (1, bn)
-    y_ref[...] += jnp.sum(a * xb, axis=1)[None, :]
+    xb = x_ref[0]                 # (1, bn)
+    y_ref[0] += jnp.sum(a * xb, axis=1, keepdims=True).astype(y_ref.dtype)
 
 
 def _spmv_mxu_kernel(cols_ref, blocks_ref, x_ref, y_ref):
@@ -50,39 +52,47 @@ def _spmv_mxu_kernel(cols_ref, blocks_ref, x_ref, y_ref):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     a = blocks_ref[0, 0]          # (bm, bn)
-    xb = x_ref[...]               # (1, bn)
-    # matvec on the systolic array: (bm,bn) @ (bn,1)
-    y_ref[...] += jax.lax.dot(
-        a, xb.T, preferred_element_type=jnp.float32).astype(y_ref.dtype).T
+    xb = x_ref[0]                 # (1, bn)
+    # matvec on the systolic array: (bm,bn) . (1,bn)^T -> (bm,1)
+    y_ref[0] += jax.lax.dot_general(
+        a, xb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("engine", "interpret"))
 def bell_spmv(blocks: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
-              *, engine: str = "vector", interpret: bool = True
-              ) -> jnp.ndarray:
-    """y = A x for A in block-ELL; returns (n_block_rows, bm)."""
+              *, engine: str = "vector",
+              interpret: Optional[bool] = None) -> jnp.ndarray:
+    """y = A x for A in block-ELL; returns (n_block_rows, bm).
+
+    ``x`` is viewed as (n/bn, 1, bn) and ``y`` as (n_block_rows, bm, 1)
+    so every block's last two dimensions equal the array's -- the TPU
+    block-shape rule -- whatever bm is.
+    """
     nbr, mb, bm, bn = blocks.shape
     assert x.shape[0] % bn == 0
-    x2 = x.reshape(-1, bn)
+    x3 = x.reshape(-1, 1, bn)
     kernel = _spmv_vpu_kernel if engine == "vector" else _spmv_mxu_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nbr, mb),
         in_specs=[
             pl.BlockSpec((1, 1, bm, bn), lambda i, j, cols: (i, j, 0, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, cols: (cols[i, j], 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, cols: (cols[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm), lambda i, j, cols: (i, 0)),
+        out_specs=pl.BlockSpec((1, bm, 1), lambda i, j, cols: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbr, bm), x.dtype),
-        interpret=interpret,
-    )(cols, blocks, x2)
+        out_shape=jax.ShapeDtypeStruct((nbr, bm, 1), x.dtype),
+        interpret=use_interpreter(interpret),
+    )(cols, blocks, x3)
+    return y.reshape(nbr, bm)
 
 
 def bell_spmv_bell(bell: BlockEll, x: jnp.ndarray, *, engine: str = "vector",
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     y = bell_spmv(bell.blocks, bell.cols, x, engine=engine,
                   interpret=interpret)
     return y.reshape(-1)[:bell.shape[0]]

@@ -3,6 +3,7 @@ aware: the advisor sees the blocked intensity I_t = t*|S|/D)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ from ...core.intensity import stencil as stencil_traits
 from ..registry import EngineOp, register
 from .defs import TABLE3_DEPTH, StencilSpec, suite
 from .ref import stencil_ref
-from .stencil import (_domain_mask, _round_up, _vpu_step, stencil_apply)
+from .stencil import stencil_apply
 
 __all__ = ["STENCIL_OP", "stencil", "suite", "TABLE3_DEPTH", "StencilSpec"]
 
@@ -46,11 +47,8 @@ def _make_inputs(rng: np.random.Generator, size: int, dtype: str = "float32"):
 
 def _engine_fn(engine: str):
     def call(u, spec: StencilSpec, *, steps: int = 1, block_rows=None,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
         br = DEFAULT_BLOCK_ROWS if block_rows is None else int(block_rows)
-        # a block must contain its own halo (t*r rows each side); clamp
-        # up so a tuned config for shallow blocking can't crash deep runs
-        br = max(br, steps * spec.radius)
         return stencil_apply(u, spec, steps=steps, engine=engine,
                              block_rows=br, interpret=interpret)
     return call
@@ -60,43 +58,29 @@ def _engine_fn(engine: str):
     jax.jit, static_argnames=("spec", "steps", "block_rows"))
 def _blocked_stencil_jnp(u: jnp.ndarray, spec: StencilSpec, *,
                          steps: int, block_rows: int) -> jnp.ndarray:
-    """Pure-jnp reproduction of ``stencil_apply``'s blocked pipeline.
+    """Pure-jnp leading-axis blocked pipeline (the timing proxy).
 
-    Same padding math and per-block trapezoid (halo concat, fused VPU
-    steps, domain re-mask), but with an unrolled XLA loop instead of a
-    Pallas grid — the off-hardware timing proxy whose wall time tracks
-    the tile choice (block count, halo recompute, padding waste).
+    Each block of ``block_rows`` rows reads a ``t*r`` halo on both sides
+    and runs ``t`` fused reference steps with the domain re-masked after
+    each -- the trapezoid of ``stencil_apply``, unrolled as XLA ops, so
+    its CPU wall time tracks the block count and halo recompute.
     """
-    true_shape = u.shape
     halo = steps * spec.radius
-    block_rows = max(block_rows, halo)
-    lane_mult = 128 if u.ndim >= 2 else 1
-    pads = [(0, 0)]
-    for ax in range(1, u.ndim):
-        right = _round_up(u.shape[ax] + 2 * halo,
-                          lane_mult) - u.shape[ax] - halo
-        pads.append((halo, right))
-    lead_round = _round_up(u.shape[0], block_rows) - u.shape[0]
-    pads[0] = (block_rows, lead_round + block_rows)
-    up = jnp.pad(u, pads)
-
-    n_tiles = (up.shape[0] - 2 * block_rows) // block_rows
+    n = u.shape[0]
+    n_tiles = -(-n // block_rows)
+    lead = [(halo, n_tiles * block_rows - n + halo)]
+    up = jnp.pad(u, lead + [(0, 0)] * (u.ndim - 1))
+    rows = jnp.arange(block_rows + 2 * halo)
     out_blocks = []
     for i in range(n_tiles):
-        top = block_rows + i * block_rows
-        tile = jax.lax.slice_in_dim(up, top - halo,
-                                    top + block_rows + halo, axis=0)
-        row0 = i * block_rows - halo
-        mask = _domain_mask(tile.shape, jnp.asarray(row0, jnp.int32),
-                            halo, true_shape, tile.dtype)
+        tile = up[i * block_rows:(i + 1) * block_rows + 2 * halo]
+        g = i * block_rows - halo + rows
+        mask = ((g >= 0) & (g < n)).astype(u.dtype)
+        mask = mask.reshape((-1,) + (1,) * (u.ndim - 1))
         for _ in range(steps):
-            tile = _vpu_step(tile, spec) * mask
+            tile = stencil_ref(tile, spec) * mask
         out_blocks.append(tile[halo:halo + block_rows])
-    out = jnp.concatenate(out_blocks, axis=0)
-    sl = [slice(0, true_shape[0])]
-    for ax in range(1, u.ndim):
-        sl.append(slice(halo, halo + true_shape[ax]))
-    return out[tuple(sl)]
+    return jnp.concatenate(out_blocks, axis=0)[:n]
 
 
 def _tune_proxy(params, u, spec: StencilSpec, *, steps: int = 1,
@@ -131,7 +115,7 @@ STENCIL_OP = register(EngineOp(
 
 def stencil(u: jnp.ndarray, spec: StencilSpec, *, steps: int = 1,
             engine: str = "auto", block_rows: int = None,
-            interpret: bool = True) -> jnp.ndarray:
+            interpret: Optional[bool] = None) -> jnp.ndarray:
     """Apply `spec` for `steps` fused timesteps.
 
     'auto' consults the advisor with the *temporally blocked* intensity

@@ -1,6 +1,8 @@
 """Public STREAM Triad op, registered as an ``EngineOp``."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,6 +53,6 @@ TRIAD_OP = register(EngineOp(
 
 
 def triad(b: jnp.ndarray, c: jnp.ndarray, q, *, engine: str = "auto",
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: Optional[bool] = None) -> jnp.ndarray:
     """a = b + q * c for arbitrary same-shaped b, c."""
     return TRIAD_OP(b, c, q, engine=engine, interpret=interpret)

@@ -13,10 +13,11 @@ All padding/tiling comes from the shared dispatch-layer wrapper.
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
-from ...core.dispatch import elementwise_call
+from ...core.dispatch import elementwise_call, mxu_dot
 
 
 def _triad_vpu_kernel(q_ref, b_ref, c_ref, o_ref):
@@ -28,13 +29,12 @@ def _triad_mxu_kernel(q_ref, b_ref, c_ref, o_ref):
     eye = jnp.eye(bn, dtype=b_ref.dtype)
     qi = (q_ref[0, 0] * eye).astype(c_ref.dtype)
     o_ref[...] = (
-        jax.lax.dot(b_ref[...], eye, preferred_element_type=jnp.float32)
-        + jax.lax.dot(c_ref[...], qi, preferred_element_type=jnp.float32)
+        mxu_dot(b_ref[...], eye) + mxu_dot(c_ref[...], qi)
     ).astype(o_ref.dtype)
 
 
 def triad_vector(b: jnp.ndarray, c: jnp.ndarray, q, *,
-                 interpret: bool = True, block_rows: int = None,
+                 interpret: Optional[bool] = None, block_rows: int = None,
                  lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_triad_vpu_kernel, (b, c), (q,),
                             interpret=interpret, block_rows=block_rows,
@@ -42,7 +42,7 @@ def triad_vector(b: jnp.ndarray, c: jnp.ndarray, q, *,
 
 
 def triad_matrix(b: jnp.ndarray, c: jnp.ndarray, q, *,
-                 interpret: bool = True, block_rows: int = None,
+                 interpret: Optional[bool] = None, block_rows: int = None,
                  lanes: int = None) -> jnp.ndarray:
     return elementwise_call(_triad_mxu_kernel, (b, c), (q,),
                             interpret=interpret, block_rows=block_rows,

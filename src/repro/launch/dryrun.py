@@ -36,7 +36,7 @@ from ..optim.adamw import AdamW
 from ..sharding import rules
 from . import steps
 from .cells import CELLS, applicable
-from .mesh import make_production_mesh, mesh_context
+from .mesh import make_production_mesh
 
 
 def _named(mesh, tree):
@@ -60,7 +60,7 @@ def _lower_one(cfg, cell, *, multi_pod: bool = False,
     vocab_ok = cfg.vocab_padded % mesh.shape["model"] == 0
     vspec = "model" if vocab_ok else None
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             bf16_params = opts.get("params_dtype") == "bf16"
             if bf16_params:
@@ -200,7 +200,7 @@ def lower_cell(arch: str, cell_name: str, *, multi_pod: bool = False,
     compiled, step, args = _lower_one(cfg, cell, multi_pod=multi_pod,
                                       opts=opts)
     t1 = time.time()
-    with mesh_context(make_production_mesh(multi_pod=multi_pod)):
+    with jax.set_mesh(make_production_mesh(multi_pod=multi_pod)):
         jc = program_cost(step, *args)      # global analytic cost
     chips = 512 if multi_pod else 256
     hw = TPU_V5E

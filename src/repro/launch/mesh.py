@@ -1,117 +1,82 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-``make_production_mesh`` is a function (never a module-level constant) so
-importing this module touches no jax device state.  The single-pod mesh is
-16x16 = 256 chips (one v5e pod); multi-pod adds a leading "pod" axis for
-2 pods = 512 chips.  The "pod" and "data" axes are both data-parallel
-(gradients reduce over both); "model" carries TP/EP.
-
-``make_auto_mesh``/``mesh_context`` paper over the jax 0.4 -> 0.5+ API
-drift (``axis_types=``/``jax.set_mesh`` only exist on newer jax) so the
-launchers, the sharded kernel executor (``repro.sharding.executor``),
-and the multi-device tests run on either.  ``data_mesh`` is the
-single-axis mesh the mesh-sharded kernel path runs under: it clamps to
-the devices this process actually has, so an off-hardware container
-(one XLA CPU device) still executes N-way ShardPlans — shard by shard
-— under a degenerate ``(1,)`` mesh.
+Every mesh here is built with ``jax.make_mesh`` and Auto axis types, and
+entered with ``jax.set_mesh``.  ``make_production_mesh`` is a function
+(never a module-level constant) so importing this module touches no jax
+device state.  The single-pod mesh is 16x16 = 256 chips (one v5e pod);
+multi-pod adds a leading "pod" axis for 2 pods = 512 chips.  The "pod"
+and "data" axes are both data-parallel (gradients reduce over both);
+"model" carries TP/EP.  ``data_mesh`` is the single-axis mesh the
+virtual (shard-by-shard) kernel path runs under: it clamps to the
+devices this process actually has, so a one-device process still
+executes N-way ShardPlans — shard by shard — under a ``(1,)`` mesh.
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.sharding import AxisType
 
 _HOST_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
 
-def _jax_backend_initialized() -> bool:
-    """Whether this process already created an XLA backend/client.
-
-    Version-tolerant: inspects ``jax._src.xla_bridge``'s backend table
-    when present (jax 0.4/0.5), and conservatively reports ``False``
-    when the internals have moved — callers then proceed and XLA
-    itself decides whether the flag still applies.
-    """
-    try:
-        from jax._src import xla_bridge
-    except Exception:  # pragma: no cover - internals moved
-        return False
-    backends = getattr(xla_bridge, "_backends", None)
-    return bool(backends)
-
-
 def host_device_count(n: int) -> int:
-    """Force the host platform to expose *n* XLA devices (pre-init only).
+    """Make this process see at least *n* devices, or say why it cannot.
 
     The one entry point ``benchmarks.run sweep --mesh N --real``, the
-    serving driver, and the multi-device tests share: sets
-    ``--xla_force_host_platform_device_count=n`` into ``XLA_FLAGS``
-    (replacing any stale value) so the CPU client created at first
-    backend use exposes *n* devices.  XLA only reads the flag at
-    client creation, so calling this after JAX initialized cannot take
-    effect: if the backend is already up with fewer than *n* devices
-    this raises ``RuntimeError`` with the fix (set the flag — or call
-    this — before the first ``jax.devices()``/computation), and if it
-    is already up with *enough* devices it is a no-op.  Returns the
-    device count the process will see.
+    serving driver, and the multi-device tests share.  It sets
+    ``--xla_force_host_platform_device_count=n`` in ``XLA_FLAGS``
+    (replacing any stale value), which the CPU client reads when JAX
+    first initializes, and then asks JAX for its devices.  A TPU host
+    brings its own chips and ignores the flag.  Raises ``RuntimeError``
+    with the fix when fewer than *n* devices exist -- on the CPU, that
+    means JAX was initialized before this call.  Returns the device count
+    the process sees.
     """
     n = int(n)
     if n < 1:
         raise ValueError(f"host_device_count needs n >= 1, got {n}")
-    if _jax_backend_initialized():
-        have = len(jax.devices())
-        if have >= n:
-            return have
-        raise RuntimeError(
-            f"JAX already initialized with {have} device(s); cannot "
-            f"force {n} host devices now. Call host_device_count({n}) "
-            f"(or export XLA_FLAGS={_HOST_COUNT_FLAG}={n}) before the "
-            f"first jax.devices()/computation — e.g. run the sweep via "
-            f"'python -m benchmarks.run sweep --mesh {n} --real', which "
-            f"sets it before touching JAX.")
-    kept = [t for t in os.environ.get("XLA_FLAGS", "").split()
-            if not t.startswith(_HOST_COUNT_FLAG)]
+    old = os.environ.get("XLA_FLAGS", "")
+    kept = [t for t in old.split() if not t.startswith(_HOST_COUNT_FLAG)]
     os.environ["XLA_FLAGS"] = " ".join(kept + [f"{_HOST_COUNT_FLAG}={n}"])
-    return n
-
-
-def make_auto_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on new jax; the Mesh's own context manager
-    (the classic ``with mesh:`` resource env) on jax < 0.5."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    devices = jax.devices()
+    if len(devices) < n:
+        os.environ["XLA_FLAGS"] = old  # it took no effect here
+        raise RuntimeError(
+            f"JAX already initialized with {len(devices)} "
+            f"{devices[0].platform} device(s); {n} are needed. On a TPU "
+            f"host, run on one with at least {n} chips. On the CPU, call "
+            f"host_device_count({n}) (or export XLA_FLAGS="
+            f"{_HOST_COUNT_FLAG}={n}) before the first jax.devices()/"
+            f"computation — e.g. run the sweep via 'python -m "
+            f"benchmarks.run sweep --mesh {n} --real', which does this "
+            f"before touching JAX.")
+    return len(devices)
 
 
 def data_mesh(num_shards: int):
-    """The 1-D "data" mesh for mesh-sharded kernel execution.
+    """The 1-D "data" mesh for shard-by-shard kernel execution.
 
     Axis width = min(num_shards, available devices), never less than 1:
     the ShardPlan still splits ``num_shards`` ways, but the mesh only
-    claims devices that exist (a single-device container gets ``(1,)``
+    claims devices that exist (a single-device process gets ``(1,)``
     and runs shards back-to-back; the scheduler's shard-parallel
     accounting is what models the N-device roof).
     """
     width = max(1, min(int(num_shards), len(jax.devices())))
-    return make_auto_mesh((width,), ("data",))
+    return jax.make_mesh((width,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The 256-chip single-pod (or 512-chip two-pod) serving mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for subprocess multi-device tests (8 host devices)."""
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -19,7 +19,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from ..configs import ARCHS, get_arch, reduced
 from ..core.dispatch import DEFAULT_DISPATCHER
@@ -30,8 +30,8 @@ from ..obs.log import LOG
 from ..optim.adamw import AdamW, cosine_schedule
 from ..runtime.train_loop import (StragglerWatchdog, TrainLoopConfig, run)
 from ..sharding import rules
-from . import mesh as mesh_mod
 from . import steps as steps_mod
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -51,12 +51,14 @@ def main():
                     choices=(None, "bf16", "int8"))
     args = ap.parse_args()
     LOG.configure(level="info")   # launcher mains narrate by default
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     d_mesh, m_mesh = map(int, args.mesh.split("x"))
-    mesh = mesh_mod.make_auto_mesh((d_mesh, m_mesh), ("data", "model"))
+    mesh = jax.make_mesh((d_mesh, m_mesh), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     # dispatch layer: a train step is ~6*P flops/token against ~16*P bytes
     # of params+grads+optimizer state -- compute-bound at any real batch,
@@ -80,7 +82,7 @@ def main():
         return params, opt.init(params)
 
     jit_step = jax.jit(step)
-    with mesh_mod.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         loop = TrainLoopConfig(
             total_steps=args.steps, ckpt_every=max(args.steps // 2, 1),
             ckpt_dir=args.ckpt_dir or f"ckpts/{cfg.name}",

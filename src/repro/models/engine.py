@@ -108,6 +108,12 @@ class DecodeEngine:
         """One token for every sequence: (B,1) tokens → (B,1,V) logits."""
         return self._step(self.params, tokens, caches, jnp.int32(index))
 
+    def lower_step(self, tokens, caches, index: int):
+        """The decode step lowered for these arguments (HLO inspection:
+        which kernels the step really launches)."""
+        return self._step.lower(self.params, tokens, caches,
+                                jnp.int32(index))
+
     # -- greedy generation -------------------------------------------------
 
     def generate(self, batch: Dict, gen: Optional[int] = None,

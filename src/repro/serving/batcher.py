@@ -79,7 +79,7 @@ class KernelBatchExecutor:
     """
 
     def __init__(self, engine: str = "auto", *, max_batch: int = 8,
-                 interpret: bool = True, seed: int = 0,
+                 interpret: Optional[bool] = None, seed: int = 0,
                  num_shards: int = 1, real_mesh: bool = False):
         self.engine = engine
         self.max_batch = max_batch

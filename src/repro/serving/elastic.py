@@ -178,7 +178,8 @@ class ChaosInjector:
 
 def redispatch_failed_shard(op, plan: ShardPlan, failed_index: int,
                             args: tuple, kwargs: Optional[dict] = None, *,
-                            engine: str = "auto", interpret: bool = True,
+                            engine: str = "auto",
+                            interpret: Optional[bool] = None,
                             dispatcher=None) -> Tuple[Any, float]:
     """Re-run one dead shard's planned ranges on surviving resources.
 
@@ -255,7 +256,7 @@ class ElasticKernelExecutor(KernelBatchExecutor):
     """
 
     def __init__(self, engine: str = "auto", *, max_batch: int = 8,
-                 interpret: bool = True, seed: int = 0,
+                 interpret: Optional[bool] = None, seed: int = 0,
                  num_shards: int = 1,
                  inputs: Optional[Dict] = None):
         super().__init__(engine, max_batch=max_batch, interpret=interpret,

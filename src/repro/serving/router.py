@@ -174,7 +174,7 @@ class OnlineKernelBatchExecutor(KernelBatchExecutor):
     """
 
     def __init__(self, engine: str = "auto", *, max_batch: int = 8,
-                 interpret: bool = True, seed: int = 0,
+                 interpret: Optional[bool] = None, seed: int = 0,
                  tuner: Optional[OnlineTuner] = None,
                  router: Optional[SLORouter] = None,
                  dispatcher=None):

@@ -7,7 +7,7 @@ registered kernel call splits (data / rowblock-with-halo / head — one
 kind per §3 family shape) and accounts the traffic each shard moves;
 :mod:`repro.sharding.executor` executes the split two ways —
 :class:`ShardedExecutor` launches shards serially through the engine
-dispatcher under a ``make_auto_mesh`` data axis and *models* the
+dispatcher under a ``jax.make_mesh`` data axis and *models* the
 N-way clock (max over shards), while :class:`MeshExecutor` lowers the
 same plan to one ``shard_map`` program over N **real** XLA host
 devices and *measures* the wall time, halo rows crossing the mesh via

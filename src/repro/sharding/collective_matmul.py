@@ -22,7 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+
+__all__ = ["rowparallel_matmul", "weight_gathered_matmul"]
 
 
 def weight_gathered_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh,
@@ -52,9 +53,9 @@ def weight_gathered_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh,
             acc = acc + cols(owner) @ wf           # overlaps next ppermute
         return acc
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(axis, None)),
-                     out_specs=P(), check_rep=False)(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(axis, None)),
+                         out_specs=P(), check_vma=False)(x, w)
 
 
 def rowparallel_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh,
@@ -70,7 +71,7 @@ def rowparallel_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh,
         out = jax.lax.psum(part, axis)
         return out.reshape(*xl.shape[:-1], wl.shape[-1])
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(*([None] * (x.ndim - 1)), axis),
-                               P(axis, None)),
-                     out_specs=P(), check_rep=False)(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(*([None] * (x.ndim - 1)), axis),
+                                   P(axis, None)),
+                         out_specs=P(), check_vma=False)(x, w)

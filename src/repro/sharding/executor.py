@@ -4,7 +4,7 @@ The runtime half of :mod:`repro.sharding.plan`: a
 :class:`ShardedExecutor` takes an op + call arguments, plans the split
 (:func:`~repro.sharding.plan.plan_for`), and launches each shard
 through ``repro.core.dispatch.DEFAULT_DISPATCHER`` under a
-``make_auto_mesh`` data axis — so every per-shard launch gets the §6
+``jax.make_mesh`` data axis — so every per-shard launch gets the §6
 engine decision and the per-(kernel, engine, dtype, hw) tuned tile
 config from the existing tuning cache, exactly as an unsharded call
 would.  Outputs are reassembled with
@@ -30,13 +30,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from ..core.dispatch import DEFAULT_DISPATCHER, Dispatcher, default_cache_key
 from ..core.timing import time_fn
 from ..obs.trace import TRACER
-from ..launch.mesh import data_mesh, make_auto_mesh, mesh_context
+from ..launch.mesh import data_mesh
 from .collective_matmul import rowparallel_matmul, weight_gathered_matmul
 from .plan import (ShardPlan, combine_outputs, first_array, plan_for,
                    shard_call)
@@ -76,7 +75,7 @@ class ShardedExecutor:
     """
 
     def __init__(self, num_shards: int, *, engine: str = "auto",
-                 interpret: bool = True, dispatcher=None):
+                 interpret: Optional[bool] = None, dispatcher=None):
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
         self.num_shards = int(num_shards)
@@ -131,7 +130,7 @@ class ShardedExecutor:
         outputs, times = [], []
         with TRACER.span("shard_run", layer="mesh", kernel=op.name,
                          kind=plan.spec.kind, shards=len(plan.shards)):
-            with mesh_context(self.mesh()):
+            with jax.set_mesh(self.mesh()):
                 for i, shard in enumerate(plan.shards):
                     sargs, skw = shard_call(plan, shard, args, kwargs)
                     t0 = time.perf_counter()
@@ -175,13 +174,16 @@ class MeshRun:
     a virtual clock), ``wall_s`` here is the *measured* wall time of
     one ``shard_map`` call over ``devices`` actual XLA devices — the
     shards genuinely ran side by side, halo rows genuinely crossed the
-    mesh via ``ppermute``.
+    mesh via ``ppermute``.  ``out_sharding`` is the sharding of the
+    mesh program's own output, before the padding is cropped off: the
+    proof of which devices the step actually ran on.
     """
 
     out: Any
     plan: ShardPlan
     devices: int
     wall_s: float
+    out_sharding: Any = None
 
     @property
     def parallel_s(self) -> float:
@@ -233,7 +235,7 @@ class MeshExecutor:
       ``ppermute`` rings (edge devices receive zeros = the domain's
       zero boundary), then applies ``t`` fused reference steps with a
       *global-row* domain mask: out-of-domain rows re-zero after every
-      step, exactly like the Pallas pipeline's ``_domain_mask``, so
+      step, exactly like the Pallas kernel's domain mask, so
       owned rows are exact despite the halo rows going progressively
       stale (the Eq. 13 trapezoid).
     * ``rowblock`` without halo (block-ELL SpMV) — block-rows split
@@ -256,17 +258,18 @@ class MeshExecutor:
         self.num_shards = int(num_shards)
         self.dispatcher = (dispatcher if dispatcher is not None
                            else DEFAULT_DISPATCHER)
-        have = len(jax.devices())
-        if have < self.num_shards:
+        devices = jax.devices()
+        n = self.num_shards
+        if len(devices) < n:
             raise RuntimeError(
-                f"MeshExecutor({self.num_shards}) needs "
-                f"{self.num_shards} devices but this process has {have}."
-                f" Force a multi-device host platform before JAX "
-                f"initializes: repro.launch.mesh.host_device_count("
-                f"{self.num_shards}), or export XLA_FLAGS="
-                f"--xla_force_host_platform_device_count="
-                f"{self.num_shards} (benchmarks.run's --real flag does "
-                f"this for you).")
+                f"MeshExecutor({n}) needs {n} devices but this process "
+                f"has {len(devices)} ({devices[0].platform}). On a TPU "
+                f"host, run on one with at least {n} chips. On the CPU, "
+                f"force a multi-device host platform before JAX "
+                f"initializes: repro.launch.mesh.host_device_count({n}), "
+                f"or export XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={n} "
+                f"(benchmarks.run's --real flag does this for you).")
         self._lowered_cache: Dict[Any, _Lowered] = {}
 
     def plan(self, op, *args, **kwargs) -> ShardPlan:
@@ -276,7 +279,8 @@ class MeshExecutor:
     # -- lowering ----------------------------------------------------------
 
     def _mesh(self, width: int, axis: str = "data"):
-        return make_auto_mesh((width,), (axis,))
+        return jax.make_mesh((width,), (axis,),
+                             axis_types=(AxisType.Auto,))
 
     def _lowered(self, op, plan: ShardPlan, args: tuple,
                  kwargs: dict) -> _Lowered:
@@ -312,9 +316,9 @@ class MeshExecutor:
                 call[i] = loc
             return op.reference(*call, **kwargs)
 
-        fn = jax.jit(shard_map(body, mesh=mesh,
-                               in_specs=(P("data"),) * len(arr_idx),
-                               out_specs=P("data"), check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P("data"),) * len(arr_idx),
+                                   out_specs=P("data"), check_vma=False))
 
         def prep(live):
             flats = []
@@ -344,9 +348,9 @@ class MeshExecutor:
             y = jnp.einsum("ijab,ijb->ia", blocks_loc, xb)
             return y.reshape(-1)
 
-        fn = jax.jit(shard_map(body, mesh=mesh,
-                               in_specs=(P("data"), P("data"), P()),
-                               out_specs=P("data"), check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P("data"), P("data"), P()),
+                                   out_specs=P("data"), check_vma=False))
 
         def prep(live):
             b = live[0]
@@ -406,8 +410,8 @@ class MeshExecutor:
                 tile = acc * mask
             return tile[halo:halo + block]
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                               out_specs=P("data"), check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                                   out_specs=P("data"), check_vma=False))
 
         def coll_body(uloc):
             lo = jax.lax.ppermute(uloc[-halo:], "data", fwd)
@@ -415,9 +419,9 @@ class MeshExecutor:
             # reduce so the transfers cannot be dead-code-eliminated
             return (lo.sum() + hi.sum()).reshape(1)
 
-        collective = jax.jit(shard_map(
+        collective = jax.jit(jax.shard_map(
             coll_body, mesh=mesh, in_specs=(P("data"),),
-            out_specs=P("data"), check_rep=False)) if width > 1 else None
+            out_specs=P("data"), check_vma=False)) if width > 1 else None
 
         def prep(live):
             up = jnp.asarray(live[0])
@@ -442,8 +446,8 @@ class MeshExecutor:
         def body(loc):
             return op.reference(loc, *rest, **kwargs)
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                               out_specs=P("data"), check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                                   out_specs=P("data"), check_vma=False))
 
         def prep(live):
             a = jnp.asarray(live[0])
@@ -470,9 +474,9 @@ class MeshExecutor:
         def body(ql, kl, vl):
             return op.reference(ql, kl, vl, *rest, **kwargs)
 
-        fn = jax.jit(shard_map(body, mesh=mesh,
-                               in_specs=(head_spec, kv_spec, kv_spec),
-                               out_specs=head_spec, check_rep=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(head_spec, kv_spec, kv_spec),
+                                   out_specs=head_spec, check_vma=False))
 
         def prep(live):
             ql, kl, vl = live[0], live[1], live[2]
@@ -518,7 +522,7 @@ class MeshExecutor:
             TRACER.emit("mesh_step", layer="mesh", start_s=t0, dur_s=wall,
                         kernel=op.name, devices=low.width)
         return MeshRun(out=low.post(out), plan=plan, devices=low.width,
-                       wall_s=wall)
+                       wall_s=wall, out_sharding=out.sharding)
 
     def measure(self, op, *args, plan: Optional[ShardPlan] = None,
                 **kwargs) -> Dict[str, float]:
@@ -604,7 +608,7 @@ class MeshExecutor:
 
         width = self.num_shards
         contract = width * _ceil_div(contract, width)
-        mesh = make_auto_mesh((width,), ("model",))
+        mesh = self._mesh(width, "model")
         rng = np.random.default_rng(seed)
         x = jnp.asarray(rng.standard_normal((rows, contract)), jnp.float32)
         w = jnp.asarray(rng.standard_normal((contract, cols)), jnp.float32)
@@ -619,9 +623,9 @@ class MeshExecutor:
             wg = jax.lax.all_gather(wl, "model", axis=0, tiled=True)
             return xl @ wg
 
-        serial = jax.jit(shard_map(serial_body, mesh=mesh,
-                                   in_specs=(P(), P("model", None)),
-                                   out_specs=P(), check_rep=False))
+        serial = jax.jit(jax.shard_map(serial_body, mesh=mesh,
+                                       in_specs=(P(), P("model", None)),
+                                       out_specs=P(), check_vma=False))
 
         for name, fn in (("ring", ring), ("serialized", serial),
                          ("rowparallel", rowpar)):

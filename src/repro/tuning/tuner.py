@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional
 
 import numpy as np
 
+from ..core.dispatch import use_interpreter
 from .cache import (SOURCE_PALLAS, SOURCE_PALLAS_INTERPRET, SOURCE_PROXY,
                     TunedEntry)
 
@@ -98,7 +99,7 @@ def _time_candidate(op, engine: str, params: Mapping[str, int],
 
 def tune_op(op, *, engine: str, dtype: str = "float32",
             size: Optional[int] = None, budget: int = 8,
-            source: str = "proxy", interpret: bool = True,
+            source: str = "proxy", interpret: Optional[bool] = None,
             hw_model: str = "", seed: int = 0,
             timer: Optional[Callable] = None,
             verbose: Optional[Callable[[str], Any]] = None,
@@ -123,6 +124,7 @@ def tune_op(op, *, engine: str, dtype: str = "float32",
             raise ValueError(f"kernel {op.name!r} has no bench_sizes; "
                              "pass size= explicitly")
         size = max(op.bench_sizes)
+    interpret = use_interpreter(interpret)
     timer = timer or _default_timer()
     rng = np.random.default_rng(seed)
     args, kwargs = op.make_inputs(rng, size, dtype)

@@ -54,13 +54,14 @@ def test_write_json_schema7(tmp_path):
              "dtype": "float32", "ref_us_per_call": 1.0,
              "tile_config": None, "mesh_shape": None,
              "shard_spec": None}]
-    env = bench_env(interpret=True, hw_model="TPU-v5e")
+    env = bench_env(hw_model="TPU-v5e")
     path = write_json("demo", recs, out_dir=str(tmp_path), env=env)
     payload = json.loads(open(path).read())
     assert payload["schema"] == SCHEMA_VERSION == 7
     assert payload["kernel"] == "demo"
     assert payload["records"] == recs
-    for key in ("jax", "numpy", "device", "interpret", "hw_model"):
+    for key in ("jax", "numpy", "device", "device_kind", "interpret",
+                "hw_model"):
         assert key in payload["env"]
     assert payload["env"]["hw_model"] == "TPU-v5e"
 
@@ -176,3 +177,38 @@ def test_compare_main_passes_identical(tmp_path, capsys):
         _write_set(d / "BENCH_scale.json", [_raw_record()])
     assert main([str(base), str(cand)]) == 0
     assert "gate passed" in capsys.readouterr().out
+
+
+def test_bench_env_records_the_interpret_decision():
+    """A record says how its kernels really ran: the backend decides,
+    never a hard-coded flag."""
+    import jax
+
+    from repro.core.dispatch import use_interpreter
+    env = bench_env(hw_model="TPU-v5e")
+    assert env["interpret"] is use_interpreter()
+    assert env["interpret"] is (jax.default_backend() != "tpu")
+    assert env["device_kind"] == jax.devices()[0].device_kind
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache
+    goes to the fixed <checkout>/.jax_cache, never a temporary path."""
+    import jax
+
+    from repro.launch import compile_cache as cc
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_enable_compilation_cache)
+    try:
+        monkeypatch.setenv(cc.CACHE_ENV, "/somewhere/else")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert cc.enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(cc.CACHE_ENV)
+        path = cc.enable_compile_cache()
+        assert path == str(cc.CHECKOUT / ".jax_cache")
+        assert (cc.CHECKOUT / "chip_smoke.py").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_enable_compilation_cache", saved[1])

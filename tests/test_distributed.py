@@ -56,7 +56,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.models import lm
         from repro.optim.adamw import AdamW
         from repro.sharding import rules
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
 
         cfg = reduced(get_arch("deepseek-7b"))
         opt = AdamW(lr=1e-3)
@@ -78,7 +78,7 @@ def test_sharded_train_step_matches_single_device():
         ps = rules.to_shardings(mesh, rules.param_pspecs(params, mesh))
         bs = {k: NamedSharding(mesh, P("data", *([None] * (v.ndim - 1))))
               for k, v in batch.items()}
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             p2, _, l2 = jax.jit(step, in_shardings=(ps, None, bs))(
                 params, opt_state, batch)
         np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
@@ -92,7 +92,7 @@ def test_sharded_train_step_matches_single_device():
 
 def test_collective_matmul_numerics():
     out = run_sub("""
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
         from repro.sharding.collective_matmul import (
             rowparallel_matmul, weight_gathered_matmul)
 
@@ -101,7 +101,7 @@ def test_collective_matmul_numerics():
         x = jnp.asarray(rng.standard_normal((16, 64)), jnp.float32)
         w = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
         want = x @ w
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             got1 = weight_gathered_matmul(x, w, mesh, axis="model")
             got2 = rowparallel_matmul(x, w, mesh, axis="model")
         np.testing.assert_allclose(np.asarray(got1), np.asarray(want),
@@ -109,7 +109,7 @@ def test_collective_matmul_numerics():
         np.testing.assert_allclose(np.asarray(got2), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
         # the ring variant must actually use collective-permute
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             hlo = jax.jit(lambda a, b: weight_gathered_matmul(
                 a, b, mesh, "model")).lower(x, w).compile().as_text()
         assert "collective-permute" in hlo, "ring not lowered to ppermute"
@@ -124,7 +124,7 @@ def test_elastic_reshard_across_meshes(tmp_path):
         from repro.models import lm
         from repro.runtime import checkpoint as ckpt
         from repro.runtime.elastic import reshard_restore, mesh_transition_plan
-        from repro.launch.mesh import make_test_mesh, mesh_context
+        from repro.launch.mesh import make_test_mesh
         from repro.sharding import rules
 
         cfg = reduced(get_arch("stablelm-12b"))
@@ -132,7 +132,7 @@ def test_elastic_reshard_across_meshes(tmp_path):
 
         mesh8 = make_test_mesh((2, 4), ("data", "model"))
         ps8 = rules.to_shardings(mesh8, rules.param_pspecs(params, mesh8))
-        with mesh_context(mesh8):
+        with jax.set_mesh(mesh8):
             sharded = jax.device_put(params, ps8)
         ckpt.save(r"{tmp_path}", 3, sharded)
 

@@ -38,6 +38,7 @@ MODULES = (
     "repro.sharding.rules",
     "repro.sharding.collective_matmul",
     "repro.launch.mesh",
+    "repro.launch.compile_cache",
 )
 
 # (module, qualname) pairs whose docstrings must cite the paper.

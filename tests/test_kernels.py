@@ -168,6 +168,21 @@ def test_stencil_temporal_blocking(engine, name, steps):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("engine", ENGINES)
+@pytest.mark.parametrize("name,shape", [("2d49pt", (24, 1100)),
+                                        ("3d27pt", (6, 10, 1100))])
+def test_stencil_lane_tiled_grid(engine, name, shape):
+    """A grid too wide for one lane block is tiled on the lane axis too:
+    overlapping windows (128-lane halos) still give the oracle's answer."""
+    spec = SPECS[name]
+    rng = np.random.default_rng(7)
+    u = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    got = stencil(u, spec, steps=2, engine=engine, block_rows=8)
+    want = stencil_ref(u, spec, steps=2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+
+
 if HAVE_HYPOTHESIS:
     @settings(max_examples=8, deadline=None)
     @given(seed=st.integers(0, 2**16), steps=st.integers(1, 3))

@@ -8,8 +8,8 @@ Covers the three legs of docs/sharding.md:
   unsharded oracle result, the stencil *because of* its Eq. 13 halo
   rows (a deliberately halo-less split is shown wrong), and the
   traffic accounting matches the Eq. 2 traits;
-* **the mesh helpers work on this jax** — `make_auto_mesh` /
-  `mesh_context` / `data_mesh` (previously untested), plus the
+* **the mesh helpers work on this jax** — `data_mesh` builds an
+  Auto-typed `jax.make_mesh` that `jax.set_mesh` enters, plus the
   dispatcher's `set_mesh` Advice integration and the serving batcher's
   shard-parallel accounting.
 """
@@ -21,7 +21,7 @@ import pytest
 
 from repro.core.dispatch import Dispatcher
 from repro.kernels import registry
-from repro.launch.mesh import data_mesh, make_auto_mesh, mesh_context
+from repro.launch.mesh import data_mesh
 from repro.sharding import (SHARD_KINDS, ShardPlan, ShardSpec,
                             ShardedExecutor, combine_outputs, plan_for,
                             shard_call, spec_for, traffic)
@@ -216,20 +216,33 @@ def test_shard_call_slices_match_manual_slicing():
 # launch.mesh helpers (previously untested)
 # --------------------------------------------------------------------------
 
-def test_make_auto_mesh_single_axis():
-    mesh = make_auto_mesh((1,), ("data",))
+def test_data_mesh_is_a_working_auto_mesh():
+    """data_mesh is jax.make_mesh with an Auto axis; jax.set_mesh enters
+    it, a sharded computation runs under it, and it exits cleanly."""
+    mesh = data_mesh(1)
     assert mesh.axis_names == ("data",)
-    assert mesh.shape["data"] == 1
-
-
-def test_mesh_context_enters_and_exits():
-    mesh = make_auto_mesh((1,), ("data",))
-    with mesh_context(mesh):
-        # inside the context a mesh-consuming computation still works
-        assert float(jax.numpy.sum(jax.numpy.ones(4))) == 4.0
-    # context exits cleanly (no resource-env leak crashing a second use)
-    with mesh_context(mesh):
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    x = jax.numpy.arange(8.0)
+    with jax.set_mesh(mesh):
+        y = jax.device_put(x, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec("data")))
+        assert float(jax.jit(jax.numpy.sum)(y)) == 28.0
+    with jax.set_mesh(mesh):  # no context leak breaks a second entry
         pass
+
+
+def test_mesh_executor_refuses_a_wider_plan_than_devices():
+    """MeshExecutor(n) never shrinks to the devices it finds (unlike the
+    virtual path's data_mesh): it names what it saw and how to get n,
+    on the chip and on the CPU."""
+    from repro.sharding import MeshExecutor
+    have = len(jax.devices())
+    with pytest.raises(RuntimeError) as info:
+        MeshExecutor(have + 1)
+    msg = str(info.value)
+    assert f"needs {have + 1} devices" in msg
+    assert jax.devices()[0].platform in msg
+    assert f"at least {have + 1} chips" in msg
 
 
 def test_data_mesh_clamps_to_available_devices():
