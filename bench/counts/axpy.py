@@ -1,0 +1,7 @@
+"""AXPY y = a*x + y: two loads, one store, a multiply-add per element
+(W = 2n, Q = 3nD)."""
+
+
+def count(entry: dict, dsize: int):
+    n = entry["n"]
+    return 2.0 * n, 3.0 * n * dsize
