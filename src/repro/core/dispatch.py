@@ -43,7 +43,6 @@ from jax.experimental import pallas as pl
 
 from ..obs.counters import roofline_sample
 from ..obs.log import LOG
-from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER
 from .advisor import DEFAULT_ADVISOR, Advice, EngineAdvisor
 from .intensity import KernelTraits
@@ -242,6 +241,7 @@ class Dispatcher:
         self._cache: Dict[Hashable, Advice] = {}
         self._hits = 0
         self._misses = 0
+        TRACER.watch_gc()
 
     @property
     def hw(self):
@@ -382,12 +382,14 @@ class Dispatcher:
         declared ``tile_space`` so a stale cache cannot smuggle unknown
         kwargs into a kernel launch.
 
-        When the :mod:`repro.obs` tracer is enabled, the call is
-        wrapped in a ``dispatch`` span (routing + tile lookup) with a
-        nested ``launch`` span around the engine body; the launch span
-        blocks on the result and carries the Eq. 2/3/4 roofline
-        counters for the measured wall time.  Disabled tracing costs
-        one attribute check.
+        Every call opens two :mod:`repro.obs` spans, which a profiler
+        trace shows as ``dispatch.route`` (engine resolution and tile
+        lookup) and ``dispatch.launch`` (the engine body: the wrapper's
+        eager ops and the Pallas enqueue); neither blocks.  When the
+        in-memory tracer is enabled, the call is also wrapped in a
+        ``dispatch`` span, and the launch span blocks on the result and
+        carries the Eq. 2/3/4 roofline counters for the measured wall
+        time.
         """
         if not TRACER.enabled:
             return self._run(op, *args, engine=engine, interpret=interpret,
@@ -401,47 +403,49 @@ class Dispatcher:
     def _run(self, op, *args, engine: str, interpret: Optional[bool],
              tile_config: Optional[Mapping[str, int]],
              _span_attrs: Optional[Dict[str, Any]] = None, **kwargs):
-        # tile params never move a kernel on the roofline: strip them
-        # before the advise path so traits factories only see semantic
-        # kwargs, then re-apply them for the launch itself
-        semantic = {k: v for k, v in kwargs.items()
-                    if k not in op.tile_space}
-        eng = self.resolve(op, *args, engine=engine, **semantic)
-        fn = op.engines.get(eng)
-        if fn is None:
-            raise ValueError(
-                f"kernel {op.name!r} has no {eng!r} variant "
-                f"(has {sorted(op.engines)})")
-        explicit = tile_config is not None
-        cfg = dict(tile_config) if explicit else \
-            self.tile_params(op, eng, *args, **semantic)
-        if cfg:
-            unknown = sorted(set(cfg) - set(op.tile_space))
-            if unknown and explicit:
+        with TRACER.span("route", layer="dispatch", kernel=op.name):
+            # tile params never move a kernel on the roofline: strip them
+            # before the advise path so traits factories only see semantic
+            # kwargs, then re-apply them for the launch itself
+            semantic = {k: v for k, v in kwargs.items()
+                        if k not in op.tile_space}
+            eng = self.resolve(op, *args, engine=engine, **semantic)
+            fn = op.engines.get(eng)
+            if fn is None:
                 raise ValueError(
-                    f"kernel {op.name!r} does not accept tile "
-                    f"parameter(s) {unknown}; its tile space is "
-                    f"{sorted(op.tile_space) or 'empty'}")
-            if unknown:
-                # a stale cache entry is advisory, never a crash: keep
-                # the params this build still knows, warn about the rest
-                import warnings
+                    f"kernel {op.name!r} has no {eng!r} variant "
+                    f"(has {sorted(op.engines)})")
+            explicit = tile_config is not None
+            cfg = dict(tile_config) if explicit else \
+                self.tile_params(op, eng, *args, **semantic)
+            if cfg:
+                unknown = sorted(set(cfg) - set(op.tile_space))
+                if unknown and explicit:
+                    raise ValueError(
+                        f"kernel {op.name!r} does not accept tile "
+                        f"parameter(s) {unknown}; its tile space is "
+                        f"{sorted(op.tile_space) or 'empty'}")
+                if unknown:
+                    # a stale cache entry is advisory, never a crash: keep
+                    # the params this build still knows, warn about the rest
+                    import warnings
 
-                from ..tuning.cache import TuningCacheWarning
-                warnings.warn(
-                    f"tuned config for {op.name}/{eng} names unknown "
-                    f"tile parameter(s) {unknown}; ignoring them "
-                    f"(tile space: {sorted(op.tile_space) or 'empty'})",
-                    TuningCacheWarning, stacklevel=2)
-                cfg = {k: v for k, v in cfg.items()
-                       if k in op.tile_space}
-            if explicit:
-                kwargs = {**kwargs, **cfg}
-            else:  # tuned values fill gaps; a None kwarg is a gap too
-                kwargs = {**kwargs, **{k: v for k, v in cfg.items()
-                                       if kwargs.get(k) is None}}
+                    from ..tuning.cache import TuningCacheWarning
+                    warnings.warn(
+                        f"tuned config for {op.name}/{eng} names unknown "
+                        f"tile parameter(s) {unknown}; ignoring them "
+                        f"(tile space: {sorted(op.tile_space) or 'empty'})",
+                        TuningCacheWarning, stacklevel=2)
+                    cfg = {k: v for k, v in cfg.items()
+                           if k in op.tile_space}
+                if explicit:
+                    kwargs = {**kwargs, **cfg}
+                else:  # tuned values fill gaps; a None kwarg is a gap too
+                    kwargs = {**kwargs, **{k: v for k, v in cfg.items()
+                                           if kwargs.get(k) is None}}
         if _span_attrs is None:
-            return fn(*args, interpret=interpret, **kwargs)
+            with TRACER.span("launch", layer="dispatch", kernel=op.name):
+                return fn(*args, interpret=interpret, **kwargs)
         # traced launch: block on the result so the span duration is
         # the call's real wall time, then attach the roofline counters
         # (modeled bytes / achieved GB/s / % of bound and ceiling)
@@ -457,9 +461,6 @@ class Dispatcher:
                 sample = roofline_sample(op.traits(*args, **semantic),
                                          self.hw, eng, dtype, dur_us)
                 launch_attrs.update(sample.as_attrs())
-                REGISTRY.counter("dispatch.launches").inc()
-                REGISTRY.histogram(
-                    f"dispatch.launch_us.{op.name}.{eng}").observe(dur_us)
             except (TypeError, ValueError) as e:
                 LOG.debug("roofline counters unavailable",
                           kernel=op.name, engine=eng, error=str(e))
@@ -516,6 +517,7 @@ def _elementwise_grid(body, scalars, arrays, *, block_rows: int,
         out_specs=tile_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lanes), arrays[0].dtype),
         interpret=interpret,
+        name=body.__name__,
     )(*scalars, *arrays)
 
 

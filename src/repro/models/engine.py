@@ -14,7 +14,12 @@ exercised by the very kernel that serves it, and the engine
 A/B lever.
 
 ``generate`` runs greedy decode and reports the prefill/decode wall
-split plus the per-step mean the ``model_verdict`` claim anchors to;
+split plus the per-step mean the ``model_verdict`` claim anchors to.
+Its host work is in :mod:`repro.obs` spans, which a profiler trace
+shows as ``engine.generate`` (the call), ``engine.prefill`` and
+``engine.step`` (each program's enqueue and its argmax) and
+``engine.wait`` (each ``block_until_ready``); the two programs are
+``jit_prefill`` and ``jit_decode_step``.
 ``cache_state``/``load_cache_state`` expose the KV caches as a plain
 pytree for ``repro.runtime.checkpoint`` round-trips.
 """
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..data.synthetic import make_batch
+from ..obs.trace import TRACER
 from . import lm
 from .advisor_map import ModelVerdict, model_verdict, step_traits
 from .config import ModelConfig
@@ -79,11 +85,18 @@ class DecodeEngine:
         self.params = (params if params is not None
                        else lm.init_params(self.cfg, jax.random.key(seed)))
         cfg_ = self.cfg
-        self._prefill = jax.jit(
-            lambda p, b: lm.prefill(p, cfg_, b, dtype=dtype, unroll=unroll))
-        self._step = jax.jit(
-            lambda p, t, c, i: lm.decode_step(p, cfg_, t, c, i,
-                                              dtype=dtype, unroll=unroll))
+
+        # named functions, so the programs are named after them
+        def prefill(p, b):
+            return lm.prefill(p, cfg_, b, dtype=dtype, unroll=unroll)
+
+        def decode_step(p, t, c, i):
+            return lm.decode_step(p, cfg_, t, c, i, dtype=dtype,
+                                  unroll=unroll)
+
+        self._prefill = jax.jit(prefill)
+        self._step = jax.jit(decode_step)
+        TRACER.watch_gc()
 
     # -- core phases -------------------------------------------------------
 
@@ -125,25 +138,30 @@ class DecodeEngine:
         fences both phases so the split is honest about async dispatch.
         """
         gen = min(self.max_gen, gen or self.max_gen)
-        t0 = time.perf_counter()
-        logits, caches = self.prefill(batch)
-        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-        jax.block_until_ready(tok)
-        t1 = time.perf_counter()
-        toks = [tok]
-        steps = 0
-        for i in range(self.prompt_len, self.prompt_len + gen - 1):
-            logits, caches = self.decode_step(tok, caches, i)
-            tok = jnp.argmax(logits[:, 0], axis=-1)[:, None]
-            toks.append(tok)
-            steps += 1
-        jax.block_until_ready(tok)
-        t2 = time.perf_counter()
-        return GenerationResult(
-            tokens=jnp.concatenate(toks, axis=1),
-            logits=logits[:, -1] if logits.ndim == 3 else logits,
-            caches=caches, prefill_s=t1 - t0, decode_s=t2 - t1,
-            decode_steps=steps)
+        with TRACER.span("generate", layer="engine"):
+            t0 = time.perf_counter()
+            with TRACER.span("prefill", layer="engine"):
+                logits, caches = self.prefill(batch)
+                tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
+            with TRACER.span("wait", layer="engine"):
+                jax.block_until_ready(tok)
+            t1 = time.perf_counter()
+            toks = [tok]
+            steps = 0
+            for i in range(self.prompt_len, self.prompt_len + gen - 1):
+                with TRACER.span("step", layer="engine"):
+                    logits, caches = self.decode_step(tok, caches, i)
+                    tok = jnp.argmax(logits[:, 0], axis=-1)[:, None]
+                toks.append(tok)
+                steps += 1
+            with TRACER.span("wait", layer="engine"):
+                jax.block_until_ready(tok)
+            t2 = time.perf_counter()
+            return GenerationResult(
+                tokens=jnp.concatenate(toks, axis=1),
+                logits=logits[:, -1] if logits.ndim == 3 else logits,
+                caches=caches, prefill_s=t1 - t0, decode_s=t2 - t1,
+                decode_steps=steps)
 
     def warmup(self, batch: Optional[Dict] = None) -> None:
         """Compile prefill + step outside any timed region."""

@@ -15,6 +15,13 @@ chaos runtime — emits :class:`SpanEvent` records into one process-wide
   makes a chaos session's timeline *replayable*: no wall timestamps
   leak in, so the same seed + chaos spec re-emits the same spans.
 
+Every :meth:`Tracer.span` is also a ``jax.profiler.TraceAnnotation``
+named ``<layer>.<name>`` (the names are :data:`PROGRAM_SPANS`), so the
+program's host spans land in a JAX profiler trace on the device trace's
+clock, whether or not the in-memory record is enabled: the profiler
+session is their switch.  :meth:`Tracer.watch_gc` adds the Python
+garbage collector's pauses there as ``host.gc``.
+
 Spans form trees (``depth``/``parent`` via the context-manager stack);
 explicitly-timed emissions (:meth:`Tracer.emit`,
 :meth:`Tracer.virtual`) attach under the currently-open wall span so a
@@ -35,13 +42,16 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import json
 import time
 from typing import (Any, Dict, Iterator, List, Mapping, Optional, Sequence,
                     Tuple)
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "SpanEvent", "TraceView", "Tracer", "TRACER", "capture",
+    "PROGRAM_SPANS", "SpanEvent", "TraceView", "Tracer", "TRACER", "capture",
     "chrome_trace", "dump_chrome_trace", "read_chrome_trace",
     "validate_chrome_trace", "write_chrome_trace",
 ]
@@ -50,6 +60,18 @@ _CLOCKS = ("wall", "virtual")
 # one Chrome-trace pid per clock so the two timelines never interleave
 # on a shared track (wall ts and virtual ts share no origin)
 _CLOCK_PID = {"wall": 1, "virtual": 2}
+
+#: Every span the program opens, as a profiler trace names it
+#: (``<layer>.<name>``).  None may start with ``window``, ``call:`` or
+#: ``batch``: the benchmark's trace reduction takes a span so named for
+#: one of its own and would assign device operations to it.
+PROGRAM_SPANS = (
+    "dispatch.dispatch", "dispatch.route", "dispatch.launch",
+    "engine.generate", "engine.prefill", "engine.step", "engine.wait",
+    "host.gc",
+    "mesh.shard_run", "mesh.reassembly", "mesh.mesh_run", "mesh.pad_prep",
+    "mesh.warmup", "mesh.mesh_measure",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,7 +136,7 @@ class Tracer:
     re-measurement).  Virtual spans and instants carry explicit
     simulated-clock times.  All emission paths early-return when
     disabled, so traced code pays one attribute check on the fast
-    path.
+    path; :meth:`span` also pays one profiler annotation.
     """
 
     def __init__(self) -> None:
@@ -122,6 +144,7 @@ class Tracer:
         self.events: List[SpanEvent] = []
         self._stack: List[int] = []  # indices of open wall spans
         self._origin: Optional[float] = None
+        self._gc_span: Optional[TraceAnnotation] = None
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -153,28 +176,55 @@ class Tracer:
              **attrs: Any) -> Iterator[Dict[str, Any]]:
         """Time the block on the wall clock; yields the attrs dict so
         the body can attach results (e.g. roofline counters) that are
-        only known once the work ran."""
-        if not self.enabled:
-            yield {}
-            return
-        parent, depth = self._parent()
-        start = self._now_us()
-        live_attrs: Dict[str, Any] = dict(attrs)
-        idx = len(self.events)
-        # placeholder so children opened inside the block can point at
-        # a real parent index; finalized (immutably replaced) on exit
-        self.events.append(SpanEvent(name=name, layer=layer, clock="wall",
-                                     start_us=start, dur_us=0.0,
-                                     depth=depth, parent=parent,
-                                     attrs=live_attrs))
-        self._stack.append(idx)
-        try:
-            yield live_attrs
-        finally:
-            self._stack.pop()
-            dur = self._now_us() - start
-            self.events[idx] = dataclasses.replace(
-                self.events[idx], dur_us=dur, attrs=dict(live_attrs))
+        only known once the work ran.
+
+        The block is always a profiler annotation ``<layer>.<name>``
+        (with ``attrs`` as its stats while a profile is being taken),
+        enabled or not; it does not block on the device."""
+        annotation = TraceAnnotation(
+            f"{layer}.{name}",
+            **(attrs if TraceAnnotation.is_enabled() else {}))
+        with annotation:
+            if not self.enabled:
+                yield {}
+                return
+            parent, depth = self._parent()
+            start = self._now_us()
+            live_attrs: Dict[str, Any] = dict(attrs)
+            idx = len(self.events)
+            # placeholder so children opened inside the block can point
+            # at a real parent index; finalized (immutably replaced) on
+            # exit
+            self.events.append(SpanEvent(
+                name=name, layer=layer, clock="wall", start_us=start,
+                dur_us=0.0, depth=depth, parent=parent, attrs=live_attrs))
+            self._stack.append(idx)
+            try:
+                yield live_attrs
+            finally:
+                self._stack.pop()
+                dur = self._now_us() - start
+                self.events[idx] = dataclasses.replace(
+                    self.events[idx], dur_us=dur, attrs=dict(live_attrs))
+
+    def watch_gc(self) -> None:
+        """Annotate every Python garbage collection as ``host.gc`` (its
+        ``generation`` a stat) while a profile is being taken.
+        Idempotent: the hook is added to ``gc.callbacks`` once."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        # a collection starts and stops on one thread, and none nests
+        # inside another, so one open annotation at a time suffices
+        if phase == "start":
+            if TraceAnnotation.is_enabled():
+                self._gc_span = TraceAnnotation(
+                    "host.gc", generation=info["generation"])
+                self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.__exit__(None, None, None)
 
     def emit(self, name: str, *, layer: str, start_s: float, dur_s: float,
              **attrs: Any) -> None:

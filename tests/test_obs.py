@@ -3,11 +3,14 @@ span-is-the-sample reconciliation against ``time_fn``, dispatch launch
 spans carrying re-derivable roofline counters, virtual-clock trace
 determinism (same seed => byte-identical Chrome-trace export),
 Chrome-trace schema validation + byte round-trips, metrics-registry
-percentiles against numpy, and the structured logger's level/capture
-contract."""
+percentiles against numpy, the structured logger's level/capture
+contract, and the program's spans in a JAX profiler trace (CPU
+profiler)."""
+import gc
 import io
 import json
 import pathlib
+import re
 import statistics
 
 import numpy as np
@@ -19,7 +22,7 @@ from repro.kernels import registry
 from repro.obs.counters import roofline_sample
 from repro.obs.log import LEVELS, StructuredLogger
 from repro.obs.metrics import Histogram, MetricsRegistry
-from repro.obs.trace import (TRACER, capture, chrome_trace,
+from repro.obs.trace import (PROGRAM_SPANS, TRACER, capture, chrome_trace,
                              dump_chrome_trace, read_chrome_trace,
                              validate_chrome_trace, write_chrome_trace)
 from repro.serving import (BatchPolicy, ContinuousBatchingScheduler,
@@ -28,6 +31,8 @@ from repro.serving.scheduler import BatchExecution
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 RUNS = REPO / "runs"
+#: the names the benchmark gives its own profiler spans
+BENCH_SPAN_PREFIXES = ("window", "call:", "batch")
 
 
 class FakeExecutor:
@@ -265,3 +270,82 @@ def test_logger_capture_collects_below_level():
     assert records[0].fields == {"a": 1}
     # stream only saw the error (captures never mute the stream)
     assert out.getvalue().splitlines() == ["[repro:error] visible"]
+
+
+# -- spans on the profiler clock --------------------------------------------
+
+def _profiled(tmp_path, fn):
+    """(name, stats) of every host event of a CPU profile of ``fn()``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    return [(e.name, dict(e.stats or {}))
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_span_of_disabled_tracer_lands_on_the_profiler_trace(tmp_path):
+    assert not TRACER.enabled
+    before = len(TRACER.events)
+
+    def work():
+        with TRACER.span("probe", layer="test", size=3):
+            pass
+    events = _profiled(tmp_path, work)
+    assert ("test.probe", {"size": 3}) in events
+    assert len(TRACER.events) == before  # the in-memory record stays off
+
+
+def test_forced_gc_is_a_host_gc_span(tmp_path):
+    TRACER.watch_gc()
+    events = _profiled(tmp_path, gc.collect)
+    assert ("host.gc", {"generation": 2}) in events
+
+
+def test_watch_gc_installs_one_hook():
+    TRACER.watch_gc()
+    n = len(gc.callbacks)
+    TRACER.watch_gc()
+    assert len(gc.callbacks) == n
+    assert sum(1 for cb in gc.callbacks if cb == TRACER._on_gc) == 1
+
+
+def test_program_span_names_are_not_benchmark_span_names():
+    assert not [n for n in PROGRAM_SPANS if n.startswith(BENCH_SPAN_PREFIXES)]
+    # every span the program's sources open is in the list
+    opened = set()
+    for path in (REPO / "src" / "repro").rglob("*.py"):
+        opened |= {f"{layer}.{name}" for name, layer in re.findall(
+            r'TRACER\.span\(\s*"([^"]+)",\s*layer="([^"]+)"',
+            path.read_text())}
+    assert opened and opened <= set(PROGRAM_SPANS)
+    assert set(PROGRAM_SPANS) - opened == {"host.gc"}
+
+
+def test_engine_generate_trace_names_spans_and_programs(tmp_path):
+    from repro.models import DecodeEngine, ModelConfig
+
+    cfg = ModelConfig(name="tiny-dense", family="dense", n_layers=2,
+                      d_model=16, n_heads=2, n_kv_heads=1, d_ff=32,
+                      vocab=50, rope_theta=1e4, pad_vocab_to=8)
+    eng = DecodeEngine(cfg, max_batch=2, prompt_len=4, max_gen=3,
+                       attention_impl="dense")
+    batch = eng.make_prompt_batch()
+    eng.warmup(batch)
+    events = _profiled(tmp_path, lambda: eng.generate(batch))
+    names = [name for name, _ in events]
+    assert names.count("engine.generate") == 1
+    assert names.count("engine.prefill") == 1
+    assert names.count("engine.step") == 2
+    assert names.count("engine.wait") == 2
+    modules = {stats.get("hlo_module") for _, stats in events}
+    assert {"jit_prefill", "jit_decode_step"} <= modules
