@@ -19,7 +19,10 @@ Its host work is in :mod:`repro.obs` spans, which a profiler trace
 shows as ``engine.generate`` (the call), ``engine.prefill`` and
 ``engine.step`` (each program's enqueue and its argmax) and
 ``engine.wait`` (each ``block_until_ready``); the two programs are
-``jit_prefill`` and ``jit_decode_step``.
+``jit_prefill`` and ``jit_decode_step``.  Construction opens
+``engine.cast_params`` (stats ``leaves`` cast, ``bytes_before`` and
+``bytes_after`` of the parameters), around the program
+``jit_cast_params`` where there is a leaf to cast.
 ``cache_state``/``load_cache_state`` expose the KV caches as a plain
 pytree for ``repro.runtime.checkpoint`` round-trips.
 """
@@ -60,8 +63,39 @@ class GenerationResult:
         return self.decode_s / self.decode_steps
 
 
+def _nbytes(leaves) -> int:
+    return sum(x.size * x.dtype.itemsize for x in leaves)
+
+
+def _cast_params(params: Any, dtype) -> Any:
+    """``lm.compute_params(params, dtype)``, computed once by the program
+    ``jit_cast_params`` inside the span ``engine.cast_params``.  Shapes
+    alone (``jax.ShapeDtypeStruct`` leaves, a compile rehearsal) give
+    the cast shapes."""
+    def cast_params(p):
+        return lm.compute_params(p, dtype)
+
+    shapes = jax.eval_shape(cast_params, params)
+    before, after = jax.tree.leaves(params), jax.tree.leaves(shapes)
+    leaves = sum(a.dtype != b.dtype for a, b in zip(before, after))
+    with TRACER.span("cast_params", layer="engine", leaves=leaves,
+                     bytes_before=_nbytes(before),
+                     bytes_after=_nbytes(after)):
+        if not leaves:
+            return params
+        if isinstance(before[0], jax.ShapeDtypeStruct):
+            return shapes
+        return jax.jit(cast_params)(params)
+
+
 class DecodeEngine:
     """Prefill + scan-over-layers greedy decode for one ModelConfig.
+
+    ``params`` (given, or drawn from ``seed``) are held as
+    ``lm.compute_params`` gives them for ``dtype``, cast once here: the
+    projections, biases, embedding, head and frontend in ``dtype``, the
+    norm gains and SSM terms in float32.  So no prefill or step casts a
+    weight; a float32 engine holds the very arrays it was given.
 
     The layer stack is *scanned*, not unrolled (``lm.decode_step``'s
     single ``lax.scan`` over the stacked parameter pytree), so compiled
@@ -82,8 +116,9 @@ class DecodeEngine:
         self.prompt_len = prompt_len
         self.max_gen = max_gen
         self.dtype = dtype
-        self.params = (params if params is not None
-                       else lm.init_params(self.cfg, jax.random.key(seed)))
+        self.params = _cast_params(
+            params if params is not None
+            else lm.init_params(self.cfg, jax.random.key(seed)), dtype)
         cfg_ = self.cfg
 
         # named functions, so the programs are named after them

@@ -1,7 +1,10 @@
 """Shared building blocks: norms, rotary embeddings, SwiGLU, initializers.
 
 All layers are pure functions over parameter pytrees (dicts of arrays).
-Parameters live in f32; compute happens in the caller-chosen dtype.
+Parameters are initialized in f32; compute happens in the caller-chosen
+dtype, each weight cast to it on use.  A decode engine holds the weights
+already cast (``lm.compute_params``), where that cast is a no-op;
+training keeps f32 master weights and casts them here.
 """
 from __future__ import annotations
 

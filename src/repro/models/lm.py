@@ -164,6 +164,33 @@ def init_params(cfg: ModelConfig, key) -> Params:
     return p
 
 
+#: The parameter leaves every consumer casts to the compute dtype on use
+#: (projections, biases, embedding, head, frontend): held already cast,
+#: they give the consumers the same operands.  Every other leaf (norm
+#: gains, ``a_log``, ``dt_bias``, ``d_skip``, conv weights) stays float32:
+#: ``rmsnorm`` and the SSM scan use those in float32 arithmetic.  A leaf
+#: missing here costs speed only; a float32 consumer cast by mistake
+#: would change the answer.
+COMPUTE_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv",
+    "wq_a", "wq_b", "wkv_a", "wkv_b",
+    "w_gate", "w_up", "w_down", "router",
+    "w_z", "w_x", "w_bc", "w_dt", "out_proj",
+    "embed", "head", "proj", "bias",
+})
+
+
+def compute_params(p: Params, dtype) -> Params:
+    """``p`` with each :data:`COMPUTE_LEAVES` leaf cast to ``dtype``, the
+    parameters a decode engine computing in ``dtype`` holds.  Float32
+    returns ``p`` itself."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return p
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: (x.astype(dtype) if path[-1].key in COMPUTE_LEAVES
+                         else x), p)
+
+
 def abstract_params(cfg: ModelConfig) -> Pytree:
     """Parameter ShapeDtypeStructs without allocating (dry-run path)."""
     return jax.eval_shape(lambda k: init_params(cfg, k),

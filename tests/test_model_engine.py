@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_arch, reduced
+from repro.configs import ARCHS, get_arch, reduced
 from repro.models import DecodeEngine, ModelConfig
 from repro.models import lm
 
@@ -193,3 +193,74 @@ def test_padding_must_not_change_argmax(small):
     np.testing.assert_array_equal(
         np.asarray(eng.generate(batch4).tokens)[:small],
         np.asarray(eng.generate(sub).tokens))
+
+
+# --------------------------------------------------------------------------
+# weights cast once == cast on every use
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", sorted(ARCHS))
+def test_cast_once_engine_matches_uncast_params(name):
+    """A bf16 engine holds its weights cast once; its prefill and decode
+    logits and greedy tokens are bit-identical to the model run on the
+    uncast float32 tree, which casts every weight on use."""
+    cfg = reduced(get_arch(name))
+    params = lm.init_params(cfg, jax.random.key(0))
+    eng = DecodeEngine(cfg, max_batch=2, prompt_len=8, max_gen=5,
+                       dtype=jnp.bfloat16, params=params)
+    ecfg = eng.cfg
+    prefill = jax.jit(lambda p, b: lm.prefill(p, ecfg, b,
+                                              dtype=jnp.bfloat16))
+    step = jax.jit(lambda p, t, c, i: lm.decode_step(
+        p, ecfg, t, c, i, dtype=jnp.bfloat16))
+
+    def greedy(got, want):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        got_tok, want_tok = (jnp.argmax(x[:, -1], axis=-1)[:, None]
+                             for x in (got, want))
+        np.testing.assert_array_equal(np.asarray(got_tok),
+                                      np.asarray(want_tok))
+        return got_tok, want_tok
+
+    batch = eng.make_prompt_batch(seed=1)
+    got, got_caches = eng.prefill(batch)
+    want, want_caches = prefill(params, batch)
+    want_caches = lm.pad_caches(want_caches, eng.max_len)
+    for i in range(eng.prompt_len, eng.prompt_len + 4):
+        got_tok, want_tok = greedy(got, want)
+        got, got_caches = eng.decode_step(got_tok, got_caches, i)
+        want, want_caches = step(params, want_tok, want_caches,
+                                 jnp.int32(i))
+    greedy(got, want)
+
+
+#: Leaves that rmsnorm and the SSM scan use in float32.
+FLOAT32_LEAVES = {"ln1", "ln2", "ln_cross", "final_norm", "enc_norm",
+                  "q_norm", "kv_norm", "norm", "a_log", "dt_bias", "d_skip",
+                  "conv_x", "conv_x_b", "conv_bc", "conv_bc_b"}
+
+
+@pytest.mark.parametrize("name", sorted(ARCHS))
+def test_compute_params_casts_projections_and_keeps_norms(name):
+    params = lm.init_params(reduced(get_arch(name)), jax.random.key(0))
+    cast = lm.compute_params(params, jnp.bfloat16)
+    for path, x in jax.tree_util.tree_leaves_with_path(cast):
+        key = path[-1].key
+        assert key in lm.COMPUTE_LEAVES | FLOAT32_LEAVES, key
+        want = jnp.bfloat16 if key in lm.COMPUTE_LEAVES else jnp.float32
+        assert x.dtype == want, (jax.tree_util.keystr(path), x.dtype)
+
+
+def test_float32_engine_holds_the_arrays_it_was_given():
+    params = lm.init_params(TINY, jax.random.key(0))
+    assert lm.compute_params(params, jnp.float32) is params
+    eng = DecodeEngine(TINY, max_batch=2, prompt_len=4, max_gen=3,
+                       dtype=jnp.float32, params=params)
+    assert eng.params is params
+    # a bf16 engine's params, given to another bf16 engine, are kept
+    bf16 = DecodeEngine(TINY, max_batch=2, prompt_len=4, max_gen=3,
+                        dtype=jnp.bfloat16, params=params)
+    again = DecodeEngine(TINY, max_batch=2, prompt_len=4, max_gen=3,
+                         dtype=jnp.bfloat16, params=bf16.params)
+    assert again.params is bf16.params

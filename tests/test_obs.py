@@ -321,6 +321,7 @@ def test_watch_gc_installs_one_hook():
 
 def test_program_span_names_are_not_benchmark_span_names():
     assert not [n for n in PROGRAM_SPANS if n.startswith(BENCH_SPAN_PREFIXES)]
+    assert "engine.cast_params" in PROGRAM_SPANS
     # every span the program's sources open is in the list
     opened = set()
     for path in (REPO / "src" / "repro").rglob("*.py"):
@@ -349,3 +350,30 @@ def test_engine_generate_trace_names_spans_and_programs(tmp_path):
     assert names.count("engine.wait") == 2
     modules = {stats.get("hlo_module") for _, stats in events}
     assert {"jit_prefill", "jit_decode_step"} <= modules
+
+
+def test_engine_construction_shows_cast_params_span(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import DecodeEngine, ModelConfig, lm
+
+    cfg = ModelConfig(name="tiny-dense", family="dense", n_layers=2,
+                      d_model=16, n_heads=2, n_kv_heads=1, d_ff=32,
+                      vocab=50, rope_theta=1e4, pad_vocab_to=8)
+    events = _profiled(tmp_path, lambda: DecodeEngine(
+        cfg, max_batch=2, prompt_len=4, max_gen=3, dtype=jnp.bfloat16,
+        attention_impl="dense"))
+    spans = [stats for name, stats in events
+             if name == "engine.cast_params"]
+    params = lm.init_params(cfg, jax.random.key(0))
+    cast = [x for path, x in jax.tree_util.tree_leaves_with_path(params)
+            if path[-1].key in lm.COMPUTE_LEAVES]
+    before = sum(x.nbytes for x in jax.tree.leaves(params))
+    after = before - sum(x.nbytes for x in cast) // 2
+    # embed, head and the 7 stacked projections of the layer block
+    assert len(cast) == 9
+    assert spans == [{"leaves": 9, "bytes_before": before,
+                      "bytes_after": after}]
+    assert "jit_cast_params" in {stats.get("hlo_module")
+                                 for _, stats in events}
