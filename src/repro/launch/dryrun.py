@@ -188,9 +188,6 @@ def lower_cell(arch: str, cell_name: str, *, multi_pod: bool = False,
     """
     opts = opts or {}
     cfg = get_arch(arch)
-    if opts.get("capacity_factor"):
-        cfg = dataclasses.replace(cfg,
-                                  capacity_factor=opts["capacity_factor"])
     cell = CELLS[cell_name]
     ok, reason = applicable(cfg, cell)
     if not ok:
@@ -277,7 +274,6 @@ def main() -> None:
     ap.add_argument("--layout", default=None, choices=(None, "fsdp", "sp"))
     ap.add_argument("--loss-chunks", type=int, default=0)
     ap.add_argument("--kv-dtype", default=None, choices=(None, "int8", "bf16"))
-    ap.add_argument("--capacity-factor", type=float, default=None)
     ap.add_argument("--cast-params", action="store_true")
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--params-dtype", default=None, choices=(None, "bf16"))
@@ -298,7 +294,7 @@ def main() -> None:
     meshes = [False, True] if args.both_meshes else [args.multipod]
     opts = {k: getattr(args, k.replace("-", "_")) for k in
             ("zero1", "remat_policy", "grad_compress", "layout",
-             "loss_chunks", "kv_dtype", "capacity_factor", "cast_params",
+             "loss_chunks", "kv_dtype", "cast_params",
              "params_dtype", "no_remat") if getattr(
                 args, k.replace("-", "_"))}
 

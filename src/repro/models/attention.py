@@ -9,7 +9,7 @@ O(S^2).
 MLA (DeepSeek-V2) caches the compressed latent + shared rope key; decode
 uses the *absorbed* formulation (w_uk folded into q, w_uv folded into the
 output projection), which is the memory-bound GEMV shape the paper's
-advisor classifies -- see DESIGN.md §5.
+advisor classifies.
 """
 from __future__ import annotations
 
@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import Params, apply_mrope, apply_rope, dense_init, rmsnorm
+from .layers import (Params, apply_mrope, apply_rope, dense_init, rmsnorm,
+                     softmax_mscale)
 
 NEG_INF = -1e30
 
@@ -182,8 +183,8 @@ def _rope_qk(q, k, q_pos, kv_pos, cfg: ModelConfig):
     if cfg.rope_kind == "mrope":
         return (apply_mrope(q, q_pos, cfg.rope_theta, cfg.mrope_sections),
                 apply_mrope(k, kv_pos, cfg.rope_theta, cfg.mrope_sections))
-    return (apply_rope(q, q_pos, cfg.rope_theta),
-            apply_rope(k, kv_pos, cfg.rope_theta))
+    return (apply_rope(q, q_pos, cfg.rope_theta, cfg.rope_yarn),
+            apply_rope(k, kv_pos, cfg.rope_theta, cfg.rope_yarn))
 
 
 def _scalar_pos(positions, cfg: ModelConfig):
@@ -350,10 +351,17 @@ def _mla_q(p: Params, x, cfg: ModelConfig):
     return q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
 
 
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """DeepSeek-V2's rotary part: pairs (2i, 2i+1), YaRN where stated."""
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_yarn,
+                      interleaved=True)
+
+
 def mla_attention(p: Params, x, cfg: ModelConfig, *, positions,
                   cache=None, cache_index=None):
     """MLA: latent-compressed KV.  Prefill caches (latent, k_rope); decode
-    runs the absorbed formulation entirely in latent space."""
+    runs the absorbed formulation entirely in latent space.  The logits
+    are scaled by 1/sqrt(nope + rope) times YaRN's mscale squared."""
     dtype = x.dtype
     b, s, _ = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
@@ -364,11 +372,12 @@ def mla_attention(p: Params, x, cfg: ModelConfig, *, positions,
     k_rope_raw = kv_a[..., r:].reshape(b, s, 1, rd)
 
     q_nope, q_rope = _mla_q(p, x, cfg)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(nope + rd, jnp.float32))
+    scale = softmax_mscale(cfg.rope_yarn) / jnp.sqrt(
+        jnp.asarray(nope + rd, jnp.float32))
 
     if cache is None:                                        # train / prefill
-        k_rope = apply_rope(k_rope_raw, positions, cfg.rope_theta)
-        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+        k_rope = _mla_rope(k_rope_raw, positions, cfg)
+        q_rope = _mla_rope(q_rope, positions, cfg)
         kv = latent @ p["wkv_b"].astype(dtype)               # (B,S,H*(nope+vd))
         kv = kv.reshape(b, s, h, nope + vd)
         k_nope, v = kv[..., :nope], kv[..., nope:]
@@ -384,9 +393,8 @@ def mla_attention(p: Params, x, cfg: ModelConfig, *, positions,
         return out @ p["wo"].astype(dtype), new_cache
 
     # ---- decode: absorbed path ----
-    kv_pos = positions
-    k_rope = apply_rope(k_rope_raw, kv_pos, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    k_rope = _mla_rope(k_rope_raw, positions, cfg)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     lat = jax.lax.dynamic_update_slice_in_dim(
         cache["latent"], latent.astype(cache["latent"].dtype), cache_index,
         axis=1)

@@ -11,6 +11,20 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``): rotary
+    frequencies past the correction range divided by ``factor``, ramped
+    linearly inside it, and the attention logits scaled by the square of
+    ``mscale_all_dim``'s magnitude factor."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense|moe|ssm|hybrid|vlm|audio
@@ -25,6 +39,7 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 1e6
     rope_kind: str = "standard"      # standard|mrope|none
+    rope_yarn: Optional[YaRN] = None  # None: plain rotary frequencies
     mrope_sections: Tuple[int, ...] = (16, 24, 24)  # t/h/w split of head_dim/2
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -35,7 +50,10 @@ class ModelConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    # the routed experts this layer holds, [start, stop) of n_experts;
+    # None: all of them.  The router always scores all n_experts.
+    held_experts: Optional[Tuple[int, int]] = None
     first_dense_layers: int = 0      # leading layers with a dense FFN
     dense_d_ff: int = 0              # FFN dim of those layers
     router_aux_weight: float = 0.01
@@ -88,6 +106,11 @@ class ModelConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.d_model // max(self.n_heads, 1))
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """[start, stop) of the routed experts held here."""
+        return self.held_experts or (0, self.n_experts)
 
     @property
     def vocab_padded(self) -> int:

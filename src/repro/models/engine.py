@@ -19,7 +19,12 @@ Its host work is in :mod:`repro.obs` spans, which a profiler trace
 shows as ``engine.generate`` (the call), ``engine.prefill`` and
 ``engine.step`` (each program's enqueue and its argmax) and
 ``engine.wait`` (each ``block_until_ready``); the two programs are
-``jit_prefill`` and ``jit_decode_step``.  Construction opens
+``jit_prefill`` and ``jit_decode_step``.  A model with routed experts
+carries its load in the caches (``moe_load``: per MoE layer, the held
+token-slots and the most one held expert took, of the prefill and
+summed or maxed over the steps), summed on the device and read once
+after the last wait, inside ``engine.moe_load`` (stats ``held_slots``,
+``max_expert_slots``, ``dropped``).  Construction opens
 ``engine.cast_params`` (stats ``leaves`` cast, ``bytes_before`` and
 ``bytes_after`` of the parameters), around the program
 ``jit_cast_params`` where there is a leaf to cast.
@@ -54,6 +59,10 @@ class GenerationResult:
     prefill_s: float           # prompt-pass wall time
     decode_s: float            # all decode steps' wall time
     decode_steps: int          # steps timed inside decode_s
+    # per MoE layer [held token-slots, most slots one held expert took]
+    # of the prefill and of the steps (summed; maxed): the caches'
+    # ``moe_load``, on the host; None without routed experts
+    moe_load: Optional[Dict[str, Any]] = None
 
     @property
     def per_step_s(self) -> float:
@@ -86,6 +95,20 @@ def _cast_params(params: Any, dtype) -> Any:
         if isinstance(before[0], jax.ShapeDtypeStruct):
             return shapes
         return jax.jit(cast_params)(params)
+
+
+def _moe_load(caches: Any) -> Optional[Dict[str, Any]]:
+    """The caches' expert load on the host, recorded as the stats of the
+    span ``engine.moe_load``; None for a model without routed experts.
+    ``dropped`` is 0: the expert layer has no capacity to drop at."""
+    if not isinstance(caches, dict) or "moe_load" not in caches:
+        return None
+    load = jax.device_get(caches["moe_load"])
+    slots = int(load["prefill"][:, 0].sum() + load["steps"][:, 0].sum())
+    most = int(max(load["prefill"][:, 1].max(), load["steps"][:, 1].max()))
+    with TRACER.span("moe_load", layer="engine", held_slots=slots,
+                     max_expert_slots=most, dropped=0):
+        return load
 
 
 class DecodeEngine:
@@ -192,11 +215,12 @@ class DecodeEngine:
             with TRACER.span("wait", layer="engine"):
                 jax.block_until_ready(tok)
             t2 = time.perf_counter()
+            load = _moe_load(caches)
             return GenerationResult(
                 tokens=jnp.concatenate(toks, axis=1),
                 logits=logits[:, -1] if logits.ndim == 3 else logits,
                 caches=caches, prefill_s=t1 - t0, decode_s=t2 - t1,
-                decode_steps=steps)
+                decode_steps=steps, moe_load=load)
 
     def warmup(self, batch: Optional[Dict] = None) -> None:
         """Compile prefill + step outside any timed region."""

@@ -42,19 +42,62 @@ def rmsnorm(w: jnp.ndarray, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 # rotary embeddings
 # --------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor 0.1 * mscale * ln(factor) + 1 (1 when
+    nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn, dim: int, theta: float) -> Tuple[int, int]:
+    """The rotary pair indices [low, high] between which YaRN ramps from
+    the original frequencies to the stretched ones: the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context."""
+    def pair(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(pair(yarn.beta_fast)), 0),
+            min(math.ceil(pair(yarn.beta_slow)), dim - 1))
+
+
+def softmax_mscale(yarn) -> float:
+    """The factor on attention logits: mscale_all_dim's magnitude factor,
+    squared (1 without YaRN)."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> jnp.ndarray:
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return freqs
+    low, high = yarn_correction_range(yarn, head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
-    """x: (..., S, H, Dh); positions: (..., S) int32."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None, interleaved: bool = False) -> jnp.ndarray:
+    """x: (..., S, H, Dh); positions: (..., S) int32.
+
+    Rotate-half over pairs (i, i + Dh/2); ``interleaved`` first gathers
+    the pairs (2i, 2i+1) into that layout (DeepSeek-V2's rotary part),
+    which the output keeps.  ``yarn`` stretches the frequencies and
+    scales cos and sin by its mscale ratio."""
     half = x.shape[-1] // 2
-    freqs = rope_frequencies(x.shape[-1], theta)          # (half,)
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    freqs = rope_frequencies(x.shape[-1], theta, yarn)    # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (...,S,half)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

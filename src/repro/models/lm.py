@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from .attention import attention, init_attention, make_cache
 from .config import ModelConfig
 from .layers import Params, dense_init, init_mlp, mlp, rmsnorm
-from .moe import init_moe, moe_ffn
+from .moe import EXPERT_LEAVES, init_moe, moe_ffn
 from .ssm import init_ssm, make_ssm_state, ssm_layer
 
 Pytree = Any
@@ -53,7 +53,10 @@ def _init_dense_layer(key, cfg: ModelConfig, d_ff: Optional[int] = None,
 
 def _dense_block(p: Params, x, cfg: ModelConfig, *, positions, cache,
                  cache_index, enc_out=None, enc_pos=None, causal=True,
-                 use_moe=None):
+                 use_moe=None, layer=None):
+    """One block.  ``layer``: the block's index in the scanned stack,
+    whose routed experts ``p["moe"]`` then holds whole (see
+    :func:`_split_experts`)."""
     from .attention import cross_attend
 
     self_cache = cache
@@ -88,10 +91,33 @@ def _dense_block(p: Params, x, cfg: ModelConfig, *, positions, cache,
         new_cache = {**new_cache, "ck": cross_kv[0], "cv": cross_kv[1]}
     moe_here = use_moe if use_moe is not None else ("moe" in p)
     if moe_here:
-        h, aux = moe_ffn(p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
+        h, aux = moe_ffn(p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg,
+                         layer=layer)
     else:
         h = mlp(p["mlp"], rmsnorm(p["ln2"], x, cfg.norm_eps))
     return x + h, new_cache, aux
+
+
+def _split_experts(layers: Params):
+    """(the layer stack without its routed experts' weights, those
+    weights whole, the layer indices); (layers, None, None) without
+    experts.  A scan hands each iteration a slice of what it scans,
+    which a Pallas kernel cannot read in place: every step would copy
+    the held experts' weights.  Closed over whole, they are read where
+    they lie, at the layer's index."""
+    if "moe" not in layers:
+        return layers, None, None
+    moe = dict(layers["moe"])
+    experts = {n: moe.pop(n) for n in EXPERT_LEAVES}
+    n = experts["w_gate"].shape[0]
+    return {**layers, "moe": moe}, experts, jnp.arange(n)
+
+
+def _with_experts(lp: Params, experts) -> Params:
+    """A scanned layer's parameters with the whole expert stack back."""
+    if experts is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], **experts}}
 
 
 def _init_ssm_layer(key, cfg: ModelConfig) -> Params:
@@ -167,14 +193,15 @@ def init_params(cfg: ModelConfig, key) -> Params:
 #: The parameter leaves every consumer casts to the compute dtype on use
 #: (projections, biases, embedding, head, frontend): held already cast,
 #: they give the consumers the same operands.  Every other leaf (norm
-#: gains, ``a_log``, ``dt_bias``, ``d_skip``, conv weights) stays float32:
-#: ``rmsnorm`` and the SSM scan use those in float32 arithmetic.  A leaf
+#: gains, ``a_log``, ``dt_bias``, ``d_skip``, conv weights, the MoE
+#: ``router``) stays float32: ``rmsnorm``, the SSM scan and the router's
+#: scores use those in float32 arithmetic.  A leaf
 #: missing here costs speed only; a float32 consumer cast by mistake
 #: would change the answer.
 COMPUTE_LEAVES = frozenset({
     "wq", "wk", "wv", "wo", "bq", "bk", "bv",
     "wq_a", "wq_b", "wkv_a", "wkv_b",
-    "w_gate", "w_up", "w_down", "router",
+    "w_gate", "w_up", "w_down",
     "w_z", "w_x", "w_bc", "w_dt", "out_proj",
     "embed", "head", "proj", "bias",
 })
@@ -303,18 +330,23 @@ def forward(p: Params, cfg: ModelConfig, batch: Dict, *,
                                         p["first_dense"], unroll=unroll)
             caches["first_dense"] = fd_caches
 
-        def body(carry, layer_p):
-            h, a = carry
-            h, kv, aux = _dense_block(layer_p, h, cfg, positions=positions,
-                                      cache=None, cache_index=None,
-                                      enc_out=enc_out, enc_pos=enc_pos)
+        scanned, experts, index = _split_experts(p["layers"])
+
+        def body(carry, xs):
+            (h, a), (layer_p, i) = carry, xs
+            h, kv, aux = _dense_block(_with_experts(layer_p, experts), h,
+                                      cfg, positions=positions, cache=None,
+                                      cache_index=None, enc_out=enc_out,
+                                      enc_pos=enc_pos, layer=i)
             for k2 in a:
                 a = dict(a, **{k2: a[k2] + aux.get(k2, 0.0)})
-            return (pin(h), a), kv
-        (x, aux_sum), kv_caches = jax.lax.scan(maybe_remat(body),
-                                               (x, aux_sum), p["layers"],
-                                               unroll=unroll)
+            return (pin(h), a), (kv, aux.get("load"))
+        (x, aux_sum), (kv_caches, loads) = jax.lax.scan(
+            maybe_remat(body), (x, aux_sum), (scanned, index), unroll=unroll)
         caches["attn"] = kv_caches
+        if loads is not None:
+            caches["moe_load"] = {"prefill": loads,
+                                  "steps": jnp.zeros_like(loads)}
 
     x = rmsnorm(p["final_norm"], x, cfg.norm_eps)
     if return_hidden:
@@ -430,7 +462,11 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int,
                                 dtype),
                 "cv": jnp.zeros((batch, se, cfg.n_kv_heads, cfg.head_dim),
                                 dtype)}
-    c = {"attn": stack(base, cfg.n_layers - cfg.first_dense_layers)}
+    n_scanned = cfg.n_layers - cfg.first_dense_layers
+    c = {"attn": stack(base, n_scanned)}
+    if cfg.n_experts:
+        zeros = jnp.zeros((n_scanned, 2), jnp.int32)
+        c["moe_load"] = {"prefill": zeros, "steps": zeros}
     if cfg.first_dense_layers:
         c["first_dense"] = stack(make_cache(cfg, batch, max_len, dtype),
                                  cfg.first_dense_layers)
@@ -508,14 +544,25 @@ def decode_step(p: Params, cfg: ModelConfig, tokens, caches: Dict,
                                  unroll=unroll)
             new_caches["first_dense"] = fd
 
+        scanned, experts, index = _split_experts(p["layers"])
+
         def body(carry, xs):
-            lp, kv = xs
-            h, kv2, _ = _dense_block(lp, carry, cfg, positions=pos,
-                                     cache=kv, cache_index=cache_index)
-            return h, kv2
-        x, kvs = jax.lax.scan(body, x, (p["layers"], caches["attn"]),
-                              unroll=unroll)
+            lp, kv, i = xs
+            h, kv2, aux = _dense_block(_with_experts(lp, experts), carry, cfg,
+                                       positions=pos, cache=kv,
+                                       cache_index=cache_index, layer=i)
+            return h, (kv2, aux.get("load"))
+        x, (kvs, loads) = jax.lax.scan(body, x,
+                                       (scanned, caches["attn"], index),
+                                       unroll=unroll)
         new_caches["attn"] = kvs
+        if loads is not None and "moe_load" in caches:
+            steps = caches["moe_load"]["steps"]
+            new_caches["moe_load"] = {
+                **caches["moe_load"],
+                "steps": jnp.stack([steps[:, 0] + loads[:, 0],
+                                    jnp.maximum(steps[:, 1], loads[:, 1])],
+                                   axis=1)}
 
     x = rmsnorm(p["final_norm"], x, cfg.norm_eps)
     return _logits(p, cfg, x), new_caches
@@ -523,7 +570,9 @@ def decode_step(p: Params, cfg: ModelConfig, tokens, caches: Dict,
 
 def prefill(p: Params, cfg: ModelConfig, batch: Dict, *, dtype=jnp.bfloat16,
             unroll: bool = False):
-    """Prompt pass: returns last-position logits + caches (KV in bf16)."""
-    logits, caches, _ = forward(p, cfg, batch, dtype=dtype, want_cache=True,
-                                remat=False, unroll=unroll)
-    return logits[:, -1:], caches
+    """Prompt pass: returns last-position logits + caches (KV in bf16).
+    The head runs at the last position only."""
+    hidden, caches, _ = forward(p, cfg, batch, dtype=dtype, want_cache=True,
+                                remat=False, unroll=unroll,
+                                return_hidden=True)
+    return _logits(p, cfg, hidden[:, -1:]), caches

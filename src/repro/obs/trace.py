@@ -68,7 +68,7 @@ _CLOCK_PID = {"wall": 1, "virtual": 2}
 PROGRAM_SPANS = (
     "dispatch.dispatch", "dispatch.route", "dispatch.launch",
     "engine.generate", "engine.prefill", "engine.step", "engine.wait",
-    "engine.cast_params",
+    "engine.cast_params", "engine.moe_load",
     "host.gc",
     "mesh.shard_run", "mesh.reassembly", "mesh.mesh_run", "mesh.pad_prep",
     "mesh.warmup", "mesh.mesh_measure",

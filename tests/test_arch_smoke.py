@@ -60,10 +60,6 @@ def test_decode_matches_forward(small_setup, name):
     cfg, params = small_setup(name)
     if cfg.enc_dec:
         pytest.skip("enc-dec decode covered in test_encdec_decode")
-    if cfg.n_experts:
-        # capacity drops only exist in the batched pass; lift the cap so
-        # teacher-forced decode is comparable
-        cfg = dataclasses.replace(cfg, capacity_factor=64.0)
     b, s = 1, 8
     batch = make_batch(cfg, b, s, seed=3)
     logits_full, _, _ = lm.forward(params, cfg, batch, dtype=jnp.float32)

@@ -144,33 +144,44 @@ else:
 # MoE invariants
 # --------------------------------------------------------------------------
 
-def test_moe_group_size_invariance_without_drops():
-    """With capacity high enough that nothing drops, the grouped dispatch
-    result must be independent of group size."""
-    cfg = dataclasses.replace(reduced(get_arch("qwen3-moe-235b-a22b")),
-                              capacity_factor=64.0)
+def test_moe_batch_split_invariance():
+    """Dropless routing: a token's output does not depend on which other
+    tokens share its batch, so the layer over a batch equals the layer
+    over each half of it (rows of the grouped product differ, the sum
+    per token is the same up to float32 rounding)."""
+    cfg = reduced(get_arch("qwen3-moe-235b-a22b"))
     from repro.models.moe import init_moe
     p = init_moe(jax.random.key(0), cfg)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model)), jnp.float32)
-    y1, _ = moe_ffn(p, x, cfg, group_size=8)
-    y2, _ = moe_ffn(p, x, cfg, group_size=32)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                               rtol=1e-4, atol=1e-5)
+    y, _ = moe_ffn(p, x, cfg)
+    halves = [moe_ffn(p, x[i:i + 1], cfg)[0] for i in range(2)]
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(jnp.concatenate(halves)),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_moe_gates_convexity():
-    """Top-k gates are renormalized: output is in the span of expert
-    outputs scaled by weights summing to ~1 per token (no drops)."""
-    cfg = dataclasses.replace(reduced(get_arch("deepseek-v2-lite-16b")),
-                              capacity_factor=64.0)
-    from repro.models.moe import init_moe
-    p = init_moe(jax.random.key(1), cfg)
-    x = jnp.zeros((1, 4, cfg.d_model), jnp.float32)
-    y, aux = moe_ffn(p, x, cfg)
-    # zero input -> zero output through SwiGLU experts
-    assert float(jnp.max(jnp.abs(y))) < 1e-5
-    assert np.isfinite(float(aux["aux_loss"]))
+    """Renormalised top-k gates (qwen3-moe) sum to 1 per token; unnormalised
+    ones (deepseek-v2-lite) are the top-k softmax probabilities, under 1.
+    A zero input gives a zero output through the SwiGLU experts."""
+    from repro.models.moe import init_moe, route
+    rng = np.random.default_rng(1)
+    for name, normed in (("qwen3-moe-235b-a22b", True),
+                         ("deepseek-v2-lite-16b", False)):
+        cfg = reduced(get_arch(name))
+        assert cfg.norm_topk_prob is normed
+        p = init_moe(jax.random.key(1), cfg)
+        xt = jnp.asarray(rng.standard_normal((8, cfg.d_model)), jnp.float32)
+        _, gates, _ = route(p, xt, cfg)
+        total = np.asarray(gates.sum(-1))
+        if normed:
+            np.testing.assert_allclose(total, 1.0, rtol=1e-6)
+        else:
+            assert (total < 1.0).all() and (total > 0.0).all()
+        y, aux = moe_ffn(p, jnp.zeros((1, 4, cfg.d_model), jnp.float32), cfg)
+        assert float(jnp.max(jnp.abs(y))) < 1e-5
+        assert np.isfinite(float(aux["aux_loss"]))
 
 
 # --------------------------------------------------------------------------

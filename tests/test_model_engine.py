@@ -235,10 +235,10 @@ def test_cast_once_engine_matches_uncast_params(name):
     greedy(got, want)
 
 
-#: Leaves that rmsnorm and the SSM scan use in float32.
+#: Leaves that rmsnorm, the SSM scan and the MoE router use in float32.
 FLOAT32_LEAVES = {"ln1", "ln2", "ln_cross", "final_norm", "enc_norm",
                   "q_norm", "kv_norm", "norm", "a_log", "dt_bias", "d_skip",
-                  "conv_x", "conv_x_b", "conv_bc", "conv_bc_b"}
+                  "conv_x", "conv_x_b", "conv_bc", "conv_bc_b", "router"}
 
 
 @pytest.mark.parametrize("name", sorted(ARCHS))

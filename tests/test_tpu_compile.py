@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import registry
+from repro.kernels.expert_gmm import expert_gmm
 from repro.kernels.spmv.ref import BlockEll
 from repro.kernels.stencil.defs import TABLE3_DEPTH, suite
 
@@ -104,3 +105,20 @@ def test_stencil_compiles_on_a_64mib_grid(one_chip, name, engine):
     _compiles_a_kernel(
         lambda u: fn(u, spec, steps=TABLE3_DEPTH[name], interpret=False),
         _arr(STENCIL_SHAPE[spec.ndim], jnp.float32, one_chip))
+
+
+#: deepseek-v2-lite's held-expert products, (rows, K, N, row tile): a
+#: decode step of 128 tokens and a prefill of 128 x 256, 8 experts held
+EXPERT_GMM = [(1024, 2048, 1408, 32), (1024, 1408, 2048, 32),
+              (200704, 2048, 1408, 512), (200704, 1408, 2048, 512)]
+
+
+@pytest.mark.parametrize("rows,k,n,tm", EXPERT_GMM)
+def test_expert_gmm_compiles_at_deepseek_v2_lite_shapes(one_chip, rows, k,
+                                                        n, tm):
+    _compiles_a_kernel(
+        lambda x, w, te, t: expert_gmm(x, w, te, t, tm, False),
+        _arr((rows, k), jnp.bfloat16, one_chip),
+        _arr((8, k, n), jnp.bfloat16, one_chip),
+        _arr((rows // tm,), jnp.int32, one_chip),
+        _arr((), jnp.int32, one_chip))
