@@ -9,7 +9,6 @@ contraction drives the MXU or the VPU.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -18,16 +17,19 @@ import numpy as np
 
 from ...core.intensity import KernelTraits
 from ..registry import EngineOp, register
-from .flash_decode import flash_decode
+from .flash_decode import block_len, flash_decode
 from .ref import decode_attention_ref
 
 __all__ = ["ATTENTION_OP", "decode_attention"]
 
-#: Static KV-block length (what untuned dispatch uses, capped at S).
+#: Static KV-block length (what untuned dispatch uses).
 DEFAULT_BLOCK_S = 512
 
-#: KV-block lengths the autotuner may try: the VMEM-residency /
-#: grid-step-count trade-off of streaming the cache once.
+#: KV-block lengths the autotuner may try, in cache positions a block
+#: (each holds every KV head of one batch row): the grid-step-count /
+#: VMEM-residency trade-off of streaming the cache once.  The kernel
+#: lowers any of them to S and to its VMEM cap for the shape and dtype
+#: (``flash_decode.block_len``), so every entry is valid at every shape.
 ATTENTION_TILE_SPACE = {"block_s": (128, 256, 512)}
 
 
@@ -40,24 +42,11 @@ def _traits(q, k, v, kv_len, *, block_s=None):
     return KernelTraits("flash_decode", work, traffic)
 
 
-def _clamp_block_s(s: int, block_s) -> int:
-    """Largest divisor of the cache length not exceeding the request.
-
-    A tuned block_s is cached per (kernel, engine, dtype) and must stay
-    valid for every cache length it meets; gcd keeps it a divisor of S
-    (power-of-two block candidates make this exact).
-    """
-    bs = min(int(block_s), s)
-    return max(math.gcd(s, bs), 1)
-
-
 def _engine_fn(engine: str):
     def call(q, k, v, kv_len, *, block_s=None,
              interpret: Optional[bool] = None):
-        if block_s is None:
-            block_s = DEFAULT_BLOCK_S
-        bs = _clamp_block_s(k.shape[1], block_s)
-        return flash_decode(q, k, v, kv_len, block_s=bs,
+        return flash_decode(q, k, v, kv_len,
+                            block_s=block_s or DEFAULT_BLOCK_S,
                             engine=engine, interpret=interpret)
     return call
 
@@ -81,38 +70,36 @@ def _chunked_decode_jnp(q, k, v, kv_len, *, block_s: int):
     """Pure-jnp blockwise online-softmax decode (the timing proxy).
 
     The same streaming structure as ``flash_decode`` — one pass over
-    the cache in (block_s, Dh) chunks with a running (m, l, acc)
+    the cache in (block_s, KH, Dh) blocks with a running (m, l, acc)
     accumulator — expressed as an unrolled XLA loop, so its CPU wall
     time follows the block-length choice the way the Pallas grid would.
     """
-    b, kh, g, dh = q.shape
+    dh = q.shape[-1]
     s = k.shape[1]
-    qf = q.reshape(b * kh, g, dh).astype(jnp.float32)
-    kf = jnp.moveaxis(k, 2, 1).reshape(b * kh, s, dh).astype(jnp.float32)
-    vf = jnp.moveaxis(v, 2, 1).reshape(b * kh, s, dh).astype(jnp.float32)
+    qf = q.astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    m = jnp.full((b * kh, g, 1), -1e30, jnp.float32)
-    length = jnp.zeros((b * kh, g, 1), jnp.float32)
-    acc = jnp.zeros((b * kh, g, dh), jnp.float32)
-    for j in range(s // block_s):
-        kb = jax.lax.slice_in_dim(kf, j * block_s, (j + 1) * block_s, axis=1)
-        vb = jax.lax.slice_in_dim(vf, j * block_s, (j + 1) * block_s, axis=1)
-        sc = jnp.einsum("bgd,bsd->bgs", qf, kb) * scale
-        pos = j * block_s + jnp.arange(block_s)[None, None, :]
+    m = jnp.full(q.shape[:-1] + (1,), -1e30, jnp.float32)
+    length = jnp.zeros_like(m)
+    acc = jnp.zeros_like(qf)
+    for start in range(0, s, block_s):
+        stop = min(start + block_s, s)
+        kb = k[:, start:stop].astype(jnp.float32)
+        vb = v[:, start:stop].astype(jnp.float32)
+        sc = jnp.einsum("bhgd,bshd->bhgs", qf, kb) * scale
+        pos = start + jnp.arange(stop - start)
         sc = jnp.where(pos < kv_len, sc, -1e30)
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         p = jnp.exp(sc - m_new)
         corr = jnp.exp(m - m_new)
         length = length * corr + p.sum(axis=-1, keepdims=True)
-        acc = acc * corr + jnp.einsum("bgs,bsd->bgd", p, vb)
+        acc = acc * corr + jnp.einsum("bhgs,bshd->bhgd", p, vb)
         m = m_new
-    out = acc / jnp.maximum(length, 1e-30)
-    return out.reshape(b, kh, g, dh).astype(q.dtype)
+    return (acc / jnp.maximum(length, 1e-30)).astype(q.dtype)
 
 
 def _tune_proxy(params, q, k, v, kv_len, *, block_s=None):
-    bs = _clamp_block_s(k.shape[1],
-                        params.get("block_s", block_s or DEFAULT_BLOCK_S))
+    bs = block_len(params.get("block_s", block_s or DEFAULT_BLOCK_S),
+                   k.shape[1], *k.shape[2:], k.dtype.itemsize)
     return _chunked_decode_jnp(q, k, v, jnp.asarray(kv_len, jnp.int32),
                                block_s=bs)
 
@@ -144,7 +131,8 @@ def decode_attention(q, k, v, kv_len, *, engine: str = "auto",
     memory-bound by ~100x on v5e; 'auto' therefore routes to the vector
     variant, with the MXU formulation one flag away (and, per the paper,
     no faster).  ``block_s=None`` lets the dispatch layer apply a tuned
-    KV-block length (or the static default of 512, capped at S).
+    KV-block length (or the static default of 512); the kernel lowers it
+    to S and to its VMEM cap.
     """
     return ATTENTION_OP(q, k, v, kv_len, engine=engine, block_s=block_s,
                         interpret=interpret)

@@ -18,6 +18,8 @@ def _hypothesis_stub():
 
 from repro.core.dispatch import DEFAULT_DISPATCHER
 from repro.kernels import registry
+from repro.kernels.attention.flash_decode import (CHUNK, VMEM_BLOCK_BUDGET,
+                                                  block_len)
 from repro.kernels.attention.ops import decode_attention
 from repro.kernels.attention.ref import decode_attention_ref
 
@@ -30,21 +32,55 @@ def _mk(b, s, kh, g, dh, dtype, seed=0):
     return q, k, v
 
 
+@pytest.mark.parametrize("engine", ["vector", "matrix"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,s,kh,g,dh,block", [
-    (1, 512, 2, 4, 64, 128),
-    (2, 1024, 4, 8, 128, 256),
-    (1, 256, 1, 1, 32, 64),
+@pytest.mark.parametrize("b,s,kh,g,dh,block,kv_len", [
+    (1, 512, 2, 4, 64, 128, 496),
+    (2, 1024, 4, 8, 128, 256, 1008),
+    (1, 256, 1, 1, 32, 64, 240),
+    # a cache length that is no power of two, kv_len == S
+    (2, 400, 2, 1, 128, 128, 400),
+    # kv_len inside the first block
+    (1, 400, 8, 4, 64, 128, 37),
+    # kv_len in the middle of a block
+    (1, 400, 8, 1, 128, 128, 200),
+    # kv_len on a block edge
+    (2, 400, 32, 1, 128, 64, 256),
+    (1, 400, 32, 4, 64, 128, 128),
+    # a block request past the VMEM cap (128 positions in float32,
+    # 256 in bfloat16 at KH 32, Dh 128)
+    (1, 640, 32, 1, 128, 4096, 600),
 ])
-def test_flash_decode_matches_ref(b, s, kh, g, dh, block, dtype):
+def test_flash_decode_matches_ref(b, s, kh, g, dh, block, kv_len, dtype,
+                                  engine):
+    """Positions past kv_len hold NaN: neither read into the result nor
+    needed, whichever block they fall in."""
     q, k, v = _mk(b, s, kh, g, dh, dtype)
-    kv_len = s - 16
-    got = decode_attention(q, k, v, kv_len, block_s=block)
+    dead = jnp.arange(s)[None, :, None, None] >= kv_len
+    got = decode_attention(q, jnp.where(dead, jnp.nan, k),
+                           jnp.where(dead, jnp.nan, v), kv_len,
+                           engine=engine, block_s=block)
     want = decode_attention_ref(q, k, v, kv_len)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("itemsize", [4, 2])
+@pytest.mark.parametrize("kh,dh", [(2, 64), (8, 128), (32, 128)])
+def test_block_len_fits_vmem_and_honours_smaller_requests(kh, dh,
+                                                          itemsize):
+    """Double-buffered K and V blocks, padded to the dtype's tile, stay
+    within the budget; a request under the cap is kept as given."""
+    sublanes = 8 * (4 // itemsize)
+    slab = -(-kh // sublanes) * sublanes * -(-dh // 128) * 128 * itemsize
+    cap = block_len(1 << 20, 1 << 20, kh, dh, itemsize)
+    assert cap % CHUNK == 0
+    assert 4 * cap * slab <= VMEM_BLOCK_BUDGET
+    assert 4 * (cap + CHUNK) * slab > VMEM_BLOCK_BUDGET
+    assert block_len(64, 1 << 20, kh, dh, itemsize) == 64
+    assert block_len(1 << 20, 400, kh, dh, itemsize) == min(cap, 400)
 
 
 if HAVE_HYPOTHESIS:

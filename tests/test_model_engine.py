@@ -264,3 +264,43 @@ def test_float32_engine_holds_the_arrays_it_was_given():
     again = DecodeEngine(TINY, max_batch=2, prompt_len=4, max_gen=3,
                          dtype=jnp.bfloat16, params=bf16.params)
     assert again.params is bf16.params
+
+
+# --------------------------------------------------------------------------
+# flash-decode reads the cache where it lies
+# --------------------------------------------------------------------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (scan
+    and pjit bodies, cond branches), in program order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def test_decode_step_hands_flash_decode_the_cache_layout():
+    """A deepseek-7b decode step passes each layer's K and V to the
+    flash-decode kernel as (B, S, KH, Dh), with no transpose of a
+    K/V-shaped array anywhere in the step (the relayout a head-major
+    kernel needed was a full read and write of the cache a layer)."""
+    cfg = reduced(get_arch("deepseek-7b"))
+    eng = DecodeEngine(cfg, max_batch=2, prompt_len=8, max_gen=4,
+                       dtype=jnp.bfloat16)
+    caches = jax.eval_shape(eng.prefill, eng.make_prompt_batch())[1]
+    tokens = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(eng._step)(eng.params, tokens, caches,
+                                      jnp.int32(eng.prompt_len))
+    kv = (2, eng.max_len, cfg.n_kv_heads, cfg.head_dim)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels, "the step runs no flash-decode kernel"
+    for e in kernels:
+        assert [v.aval.shape for v in e.invars[2:4]] == [kv, kv]
+    relaid = [e for e in eqns if e.primitive.name == "transpose"
+              and any(sorted(v.aval.shape) == sorted(kv)
+                      for v in e.invars)]
+    assert not relaid, [(e.invars[0].aval, e.params) for e in relaid]
