@@ -51,26 +51,6 @@ from repro.sharding.executor import MeshExecutor
 from .common import bench_env, emit, time_fn, write_json
 
 
-def _tile_config_field(op, engine: str, dtype: str) -> Optional[dict]:
-    """The tuned-tile evidence for one sweep point, or None (defaults).
-
-    Carries the tuner's own measurements (``tuned_us`` -- the cache's
-    ``best_us`` -- vs ``default_us``) alongside the params so the
-    claims report can render tuned-vs-default deltas without re-timing
-    anything.
-    """
-    entry = DEFAULT_DISPATCHER.tuning.lookup(
-        op.name, engine, dtype, DEFAULT_DISPATCHER.hw.name)
-    if entry is None:
-        return None
-    return {
-        "params": {k: int(v) for k, v in sorted(entry.params.items())},
-        "tuned_us": round(entry.best_us, 1),
-        "default_us": round(entry.default_us, 1),
-        "source": entry.source,
-    }
-
-
 def _shard_spec_field(op, plan, args, kw, hw) -> dict:
     """The schema-5 ``shard_spec`` evidence for one mesh sweep point.
 
@@ -157,8 +137,6 @@ def records_for(op, mesh: int = 1, real: bool = False) -> List[dict]:
                     "mesh_wall_us": mesh_field["mesh_wall_us"],
                 }
             for engine in sorted(op.engines):
-                # runs with the tuned tile config when one is cached --
-                # the correctness check covers the tiles we'd deploy
                 if sharded is not None:
                     run = sharded.run(op, *args, engine=engine,
                                       plan=plan, **kw)
@@ -197,7 +175,6 @@ def records_for(op, mesh: int = 1, real: bool = False) -> List[dict]:
                     "engine_auto": advice.engine,
                     "pred_us_v5e": round(pred_us, 3),
                     "mxu_ceiling": advice.max_speedup_matrix,
-                    "tile_config": _tile_config_field(op, engine, dtype),
                     "mesh_shape": [mesh] if mesh > 1 else None,
                     "shard_spec": shard_field,
                     "mesh_exec": mesh_field,
@@ -207,7 +184,6 @@ def records_for(op, mesh: int = 1, real: bool = False) -> List[dict]:
 
 def rows(names: Optional[Iterable[str]] = None,
          json_dir: Optional[str] = "runs",
-         tuned: Optional[str] = None,
          mesh: int = 1,
          real: bool = False,
          trace_out: Optional[str] = None) -> List[dict]:
@@ -219,10 +195,6 @@ def rows(names: Optional[Iterable[str]] = None,
     reconciliation captures nest inside this outer one, so the export
     sees everything they saw.
     """
-    if tuned is not None:
-        # sweep with tuned tile configs: dispatch consults the cache
-        # for every launch and each record says which tiles it used
-        DEFAULT_DISPATCHER.load_tuned(tuned)
     mesh = max(1, int(mesh))
     # the dispatcher plans shard specs onto its memoized Advice for the
     # sweep's mesh width (restored after: rows() must not leak mesh
@@ -274,9 +246,6 @@ def _csv_rows(recs: List[dict], mesh: int) -> List[dict]:
     """The stdout CSV projection of one kernel's sweep records."""
     out = []
     for r in recs:
-        cfg = r.get("tile_config")
-        tiles = "" if not cfg else ";tiles=" + ",".join(
-            f"{k}={v}" for k, v in sorted(cfg["params"].items()))
         spec = r.get("shard_spec")
         shard = "" if not spec else (
             f";shards={spec['num_shards']};halo={spec['halo']};"
@@ -296,7 +265,7 @@ def _csv_rows(recs: List[dict], mesh: int) -> List[dict]:
                         f"I={r['intensity']:.4f};"
                         f"auto={r['engine_auto']};"
                         f"mxu_ceiling={r['mxu_ceiling']:.4f}x;"
-                        f"err={r['max_err']:.2e}" + tiles + shard),
+                        f"err={r['max_err']:.2e}" + shard),
         })
     return out
 

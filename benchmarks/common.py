@@ -15,9 +15,8 @@ from typing import List, Optional, TextIO
 
 import jax
 
-# canonical implementation lives in the library so the autotuner and
-# the harness can never drift apart; re-exported here for all existing
-# benchmark/test consumers
+# canonical implementation lives in the library; re-exported here for
+# all existing benchmark/test consumers
 from repro.core.timing import Timing, time_fn
 
 __all__ = ["SCHEMA_VERSION", "SERVING_SCHEMA_VERSION", "Timing",
@@ -27,9 +26,8 @@ __all__ = ["SCHEMA_VERSION", "SERVING_SCHEMA_VERSION", "Timing",
 #: Version of the BENCH_<kernel>.json file format.  Schema 1 was a bare
 #: list of records; schema 2 wraps the records with environment
 #: metadata (jax version, device kind, interpret flag, hardware model);
-#: schema 3 adds a per-record ``tile_config`` field (the tuned tile
-#: params the launch used plus the tuner's tuned-vs-default timings,
-#: or null when dispatch fell back to static defaults); schema 4 is
+#: schema 3 added a per-record tile-config field (written while the
+#: repo carried a tile autotuner; no longer written); schema 4 is
 #: the *serving* record format (see SERVING_SCHEMA_VERSION); schema 5
 #: adds the mesh fields — per-record ``mesh_shape`` (the requested
 #: mesh, e.g. ``[2]``) and ``shard_spec`` (the ShardPlan the point ran
@@ -120,9 +118,8 @@ def write_json(kernel: str, records: List[dict], out_dir: str = "runs",
 
     Schema 7: ``{"schema": 7, "kernel": ..., "env": {...}, "records":
     [...]}`` with one record per (engine, size, dtype) sweep point
-    (including its ``tile_config``, if tuned, its
-    ``mesh_shape``/``shard_spec`` when swept under a mesh, and its
-    observability ``trace`` block) so the perf
+    (including its ``mesh_shape``/``shard_spec`` when swept under a
+    mesh, and its observability ``trace`` block) so the perf
     trajectory is diffable across PRs and auditable by the
     ``repro.report`` claim checks.  Mesh sweeps (``mesh > 1``) land in
     ``BENCH_<kernel>_mesh<N>.json`` beside the single-device baseline
@@ -137,8 +134,7 @@ def write_json(kernel: str, records: List[dict], out_dir: str = "runs",
 
 def write_serving_json(kernel: str, records: List[dict],
                        out_dir: str = "runs",
-                       env: Optional[dict] = None, mesh: int = 1,
-                       suffix: str = "") -> str:
+                       env: Optional[dict] = None, mesh: int = 1) -> str:
     """Write one kernel's serving sessions to BENCH_serve_<kernel>.json.
 
     Schema 5: ``{"schema": 5, "kind": "serving", "kernel": ..., "env":
@@ -148,12 +144,10 @@ def write_serving_json(kernel: str, records: List[dict],
     ``benchmarks/compare.py --kind serving``.  Mesh sessions
     (``mesh > 1``) land in ``BENCH_serve_<kernel>_mesh<N>.json`` beside
     the single-device baseline instead of clobbering it, mirroring the
-    bench-sweep convention; *suffix* (e.g. ``"_online"`` for
-    ``serve --online-tune`` sessions) keeps other session variants
-    separate the same way.
+    bench-sweep convention.
     """
-    name = (f"BENCH_serve_{kernel}{suffix}.json" if mesh <= 1
-            else f"BENCH_serve_{kernel}{suffix}_mesh{mesh}.json")
+    name = (f"BENCH_serve_{kernel}.json" if mesh <= 1
+            else f"BENCH_serve_{kernel}_mesh{mesh}.json")
     return _write_record_file(name, kernel, SERVING_SCHEMA_VERSION,
                               records, out_dir, env,
                               extra={"kind": "serving"})

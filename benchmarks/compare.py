@@ -23,12 +23,6 @@ when
 * a joined pair of **chaos** serving sessions (both sides carrying an
   ``events`` block from ``serve --chaos``) drops its availability
   under failure by more than the same threshold,
-* a joined pair of **online-tuned** sessions (both sides carrying a
-  ``tuning`` block from ``serve --online-tune``) grows its total
-  bandit regret (``regret_us_total``) by more than the same threshold
-  — exploration getting more expensive is an adaptive-control
-  regression, gated alongside the p99 drift the shared tail gate
-  already catches,
 * a joined serving session pair disagrees on its load knobs
   (rate/duration/SLO/seed/mesh width/chaos spec — sessions under
   different offered load, sharding, or injected adversary are not
@@ -42,9 +36,7 @@ Bench sweep points join on (kernel, engine, size, dtype, mesh width) —
 a 2-way-mesh point only ever gates against the 2-way baseline, and a
 clamped sweep (a mesh wider than the kernel's split extent) still
 joins the width it was requested at; serving sessions join on
-(kernel, engine, workload, size, dtype, mesh width, tuning mode) — an
-online-tuned session only ever gates against the online baseline,
-never the statically-tuned twin.  ``--kind``
+(kernel, engine, workload, size, dtype, mesh width).  ``--kind``
 restricts the gate to one record kind (``bench``/``serving``; default
 ``all``) so CI can gate a fast kernel sweep and a serve smoke run
 against different candidate directories; ``--mesh N`` restricts both
@@ -256,10 +248,6 @@ def gate(baseline_dir: str, candidate_dir: str, threshold: float = 0.25,
                 # too: a chaos session only gates against a baseline
                 # that suffered the same adversary
                 return (rec.events or {}).get("spec")
-            if field == "tune_budget":
-                # exploration budget shapes both regret and the tail:
-                # online sessions only gate against the same budget
-                return (rec.tuning or {}).get("budget")
             value = getattr(rec, field)
             if field == "num_shards":
                 return value or 1  # legacy records: None = unsharded
@@ -275,7 +263,7 @@ def gate(baseline_dir: str, candidate_dir: str, threshold: float = 0.25,
                 f"{f}={_knob(base[key], f)} vs {_knob(cand[key], f)}"
                 for f in ("rate_rps", "duration_s", "slo_ms", "seed",
                           "max_batch", "max_wait_ms", "num_shards",
-                          "mesh_exec_mode", "chaos_spec", "tune_budget")
+                          "mesh_exec_mode", "chaos_spec")
                 if _knob(base[key], f) != _knob(cand[key], f)]
             if mismatched:
                 failures.append(Failure(
@@ -301,17 +289,6 @@ def gate(baseline_dir: str, candidate_dir: str, threshold: float = 0.25,
                              float(c_ev.get("availability", 0.0)),
                              "availability", "", threshold, "goodput",
                              failures, lower_is_better=False)
-            b_tu, c_tu = base[key].tuning, cand[key].tuning
-            if b_tu and c_tu:
-                # both sides tuned online under the same budget: total
-                # regret is the price the bandit paid to explore —
-                # growth means the adaptive loop is converging slower
-                # (or to worse tiles), a regression the p99 gate alone
-                # can hide behind queueing noise
-                _gate_metric(key, float(b_tu.get("regret_us_total", 0.0)),
-                             float(c_tu.get("regret_us_total", 0.0)),
-                             "regret_us_total", "us", threshold, "perf",
-                             failures)
 
     if empty:
         # an over-narrow --kernels/--kind filter must not pass vacuously

@@ -11,8 +11,6 @@ list to maintain.
   sweep          -- alias for ``kernels`` (the name the mesh walkthrough
                     in docs/sharding.md uses)
   <kernel name>  -- one registered kernel (e.g. ``scale``, ``triad``)
-  tune           -- tile-config autotuner -> tuned.json (see
-                    ``benchmarks.tune`` for its flags)
   serve          -- request-level serving sessions (loadgen ->
                     continuous batching -> latency percentiles; see
                     ``benchmarks.serve`` for its flags)
@@ -21,15 +19,6 @@ list to maintain.
 Prints ``name,us_per_call,derived`` CSV rows; kernel sweeps also write
 ``runs/BENCH_<kernel>.json`` (override the directory with ``--out DIR``
 to produce a candidate set for ``benchmarks/compare.py``).
-
-``--tuned tuned.json`` sweeps with tuned tile configs: dispatch
-consults the cache (schema-1 ``tuned.json``; entries keyed by
-(kernel, engine, dtype, hw_model) carrying ``params`` plus the tuner's
-``best_us``/``default_us`` timings -- see docs/tuning.md) for every
-launch, and each sweep point records the tiles it ran under in its
-``tile_config`` field as ``params`` plus ``tuned_us``/``default_us``,
-where ``tuned_us`` is the cache entry's ``best_us`` restated under the
-record-side name.
 
 ``--mesh N`` sweeps under an N-way data-axis mesh (``repro.sharding``):
 engine variants execute shard by shard (halo exchange included), and
@@ -97,10 +86,6 @@ def main(argv: Optional[List[str]] = None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if argv and argv[0] == "tune":
-        # the tuner has its own argparse surface (budget, out, ...)
-        from . import tune
-        raise SystemExit(tune.main(argv[1:]))
     if argv and argv[0] == "serve":
         # the serving driver has its own argparse surface (workload,
         # rate, duration, mesh, ...)
@@ -110,7 +95,6 @@ def main(argv: Optional[List[str]] = None) -> None:
     taken = _take_flag(argv, "--out", "a directory argument")
     if taken is not None:
         out_dir = taken
-    tuned = _take_flag(argv, "--tuned", "a tuned.json path argument")
     mesh_arg = _take_flag(argv, "--mesh", "a shard-count argument")
     trace_out = _take_flag(argv, "--trace", "an output path argument")
     real = "--real" in argv
@@ -134,10 +118,6 @@ def main(argv: Optional[List[str]] = None) -> None:
         from repro.launch.mesh import host_device_count
         host_device_count(mesh)
     if argv and argv[0] == "report":
-        if tuned is not None:
-            # the report is a pure function of runs/; a tuned cache
-            # only affects sweeps, so silently ignoring it would lie
-            raise SystemExit("--tuned only applies to kernel sweeps")
         if mesh_arg is not None:
             raise SystemExit("--mesh only applies to kernel sweeps")
         if real:
@@ -156,8 +136,6 @@ def main(argv: Optional[List[str]] = None) -> None:
                  for k in which)
     if out_given and not sweeps:
         raise SystemExit("--out only applies to kernel sweeps or report")
-    if tuned is not None and not sweeps:
-        raise SystemExit("--tuned only applies to kernel sweeps")
     if mesh_arg is not None and not sweeps:
         raise SystemExit("--mesh only applies to kernel sweeps")
     if real and not sweeps:
@@ -169,17 +147,15 @@ def main(argv: Optional[List[str]] = None) -> None:
         if key in THEORY:
             emit(THEORY[key].rows())
         elif key in ("kernels", "sweep"):
-            emit(bench_kernels.rows(json_dir=out_dir, tuned=tuned,
-                                    mesh=mesh, real=real,
-                                    trace_out=trace_out))
+            emit(bench_kernels.rows(json_dir=out_dir, mesh=mesh,
+                                    real=real, trace_out=trace_out))
         elif key in kernel_names:
-            emit(bench_kernels.rows([key], json_dir=out_dir, tuned=tuned,
-                                    mesh=mesh, real=real,
-                                    trace_out=trace_out))
+            emit(bench_kernels.rows([key], json_dir=out_dir, mesh=mesh,
+                                    real=real, trace_out=trace_out))
         else:
             raise SystemExit(
                 f"unknown benchmark {key!r}; have "
-                f"{sorted(THEORY) + ['kernels', 'report', 'serve', 'sweep', 'tune'] + sorted(kernel_names)}")
+                f"{sorted(THEORY) + ['kernels', 'report', 'serve', 'sweep'] + sorted(kernel_names)}")
 
 
 if __name__ == "__main__":

@@ -25,18 +25,12 @@ virtual clock and an open-loop workload, so it composes with
 ``--mesh`` but refuses ``--real``, ``--workload closed``, and
 ``--workload lm``.
 
-``--online-tune [--slo-route]`` adds one ``engine='auto'`` session per
-kernel served by :class:`~repro.serving.router.OnlineKernelBatchExecutor`:
-a budgeted UCB bandit (``repro.tuning.online``) re-tunes tile shapes
-from measured batch compute inside the virtual clock, warm-started
-from the loaded ``tuned.json``; ``--slo-route`` additionally lets the
-:class:`~repro.serving.router.SLORouter` pick shard width and gate
-exploration from queue depth + SLO headroom.  These sessions land in
-``BENCH_serve_<kernel>_online.json`` with a ``tuning`` block (per-key
-arms, decision events with observed µs and regret, router decisions)
-that the ``online_ceiling`` claim replays byte-identically, and the
-bandit's winners persist to ``<out>/tuned-online.json`` through the
-cache's faster-wins merge.
+``--slo-route`` serves every session through
+:class:`~repro.serving.router.RoutedKernelBatchExecutor`: the
+:class:`~repro.serving.router.SLORouter` picks the shard width at each
+batch dequeue from queue depth + SLO headroom, starting at width 1 on
+the virtual clock.  Routed records carry no marker of their own, so
+write them to their own ``--out`` directory.
 
 ``--trace-out PATH`` exports the sweep's virtual-clock span timeline
 (admissions, queue waits, batch launches; chaos injections and
@@ -141,25 +135,11 @@ def _parse(argv: Optional[List[str]]) -> argparse.Namespace:
                         "Chrome-trace JSON; --trace names the "
                         "*workload input*, this names the "
                         "observability output")
-    p.add_argument("--tuned", default=None,
-                   help="tuned.json for tile-aware packing/dispatch")
-    p.add_argument("--online-tune", action="store_true",
-                   help="add one engine='auto' session per kernel whose "
-                        "tiles are re-tuned live by the budgeted UCB "
-                        "bandit (repro.tuning.online), warm-started "
-                        "from the loaded tuned.json; records land in "
-                        "BENCH_serve_<kernel>_online.json with a "
-                        "tuning block the online_ceiling claim "
-                        "replays, and the winners persist to "
-                        "<out>/tuned-online.json via faster-wins merge")
     p.add_argument("--slo-route", action="store_true",
-                   help="with --online-tune: pick shard width and gate "
-                        "bandit exploration from queue depth + SLO "
-                        "headroom (repro.serving.router.SLORouter) "
-                        "instead of the roofline alone")
-    p.add_argument("--tune-budget", type=int, default=8,
-                   help="online bandit exploration pulls per "
-                        "(kernel, engine, dtype, shard) key (default 8)")
+                   help="pick each session's shard width from queue "
+                        "depth + SLO headroom "
+                        "(repro.serving.router.SLORouter), starting at "
+                        "width 1 on the virtual clock")
     p.add_argument("--out", default="runs",
                    help="record directory (default runs)")
     return p.parse_args(argv)
@@ -272,26 +252,19 @@ def main(argv: Optional[List[str]] = None) -> int:
         args.max_batch = 4 if lm else 8
     if args.slo_ms is None:
         args.slo_ms = 30000.0 if lm else 50.0
-    if args.slo_route and not args.online_tune:
-        raise SystemExit("--slo-route requires --online-tune (the "
-                         "router's exploration gate drives the bandit)")
-    if args.online_tune:
-        # the bandit observes measured batch walls inside the virtual
-        # clock and (with --slo-route) owns the mesh width itself
+    if args.slo_route:
+        # the router owns the mesh width on the virtual clock
         if lm:
-            raise SystemExit("--online-tune is not supported for "
+            raise SystemExit("--slo-route is not supported for "
                              "--workload lm (kernel sessions only)")
         if args.chaos:
-            raise SystemExit("--online-tune composes with the standard "
-                             "session, not --chaos (chaos replays a "
-                             "fault-free twin; live re-tuning would "
-                             "fork the legs)")
+            raise SystemExit("--slo-route composes with the standard "
+                             "session, not --chaos (the elastic "
+                             "session owns the width there)")
         if args.real or args.mesh > 1:
-            raise SystemExit("--online-tune owns the mesh width (the "
+            raise SystemExit("--slo-route owns the mesh width (the "
                              "router grows and shrinks it): drop "
                              "--mesh/--real")
-        if args.tune_budget < 1:
-            raise SystemExit("--tune-budget must be >= 1")
     injector = None
     if args.chaos:
         # validate the adversary up front: the elastic runtime needs a
@@ -325,8 +298,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         # --xla_force_host_platform_device_count exactly once)
         from repro.launch.mesh import host_device_count
         host_device_count(args.mesh)
-    if args.tuned:
-        DEFAULT_DISPATCHER.load_tuned(args.tuned)
     explicit = args.kernels is not None and args.kernels != "all"
     names = (tuple(args.kernels.split(",")) if explicit
              else registry.names() if args.kernels == "all"
@@ -366,7 +337,6 @@ def main(argv: Optional[List[str]] = None) -> int:
           "slo_attainment")
 
     def _sweep() -> int:
-        online_entries = []
         for kernel in names:
             records = []
             # per-kernel view of the once-parsed trace (None for the
@@ -380,7 +350,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     rate_rps=args.rate, duration_s=args.duration,
                     size=args.size, dtype=args.dtype, seed=args.seed,
                     policy=policy, slo=slo, trace_path=args.trace,
-                    num_shards=args.mesh, real_mesh=args.real)
+                    num_shards=args.mesh, real_mesh=args.real,
+                    slo_route=args.slo_route)
                 if injector is not None:
                     from repro.serving import ElasticSession
                     session = ElasticSession(cfg, injector=injector)
@@ -395,83 +366,9 @@ def main(argv: Optional[List[str]] = None) -> int:
             path = write_serving_json(kernel, records, args.out, env=env,
                                       mesh=args.mesh)
             print(f"# wrote {path}")
-            if args.online_tune:
-                record, summary, entries = _online_session(args, kernel,
-                                                           policy, slo,
-                                                           source)
-                online_entries.extend(entries)
-                print(f"{kernel},{record['engine']},{args.workload},"
-                      f"{summary.completed},{summary.p50_ms:.3f},"
-                      f"{summary.p99_ms:.3f},{summary.goodput_rps:.3f},"
-                      f"{summary.slo_attainment:.4f}")
-                path = write_serving_json(kernel, [record], args.out,
-                                          env=env, suffix="_online")
-                print(f"# wrote {path}")
-        if online_entries:
-            print(f"# wrote {_persist_online(args.out, online_entries)}")
         return 0
 
     return _run_traced(args, _sweep)
-
-
-def _online_session(args: argparse.Namespace, kernel: str,
-                    policy: BatchPolicy, slo: SLO, source):
-    """One ``--online-tune`` session: auto-routed engine, live bandit.
-
-    Builds the tuner/router/executor stack here (rather than letting
-    ``run_session`` own it) so the sweep can persist the bandit's
-    winners after the session; always restores the global dispatcher's
-    mesh width on the way out.
-    """
-    from repro.serving.router import OnlineKernelBatchExecutor, SLORouter
-    from repro.tuning.online import OnlineTuner
-
-    tuner = OnlineTuner(args.tune_budget,
-                        cache=DEFAULT_DISPATCHER.tuning.cache,
-                        hw_model=DEFAULT_DISPATCHER.hw.name)
-    router = SLORouter(slo_ms=args.slo_ms) if args.slo_route else None
-    executor = OnlineKernelBatchExecutor(
-        engine="auto", max_batch=args.max_batch, seed=args.seed,
-        tuner=tuner, router=router)
-    cfg = SessionConfig(
-        kernel=kernel, workload=args.workload, engine="auto",
-        rate_rps=args.rate, duration_s=args.duration, size=args.size,
-        dtype=args.dtype, seed=args.seed, policy=policy, slo=slo,
-        trace_path=args.trace, online_tune=True,
-        slo_route=args.slo_route, tune_budget=args.tune_budget)
-    try:
-        _, summary, record = run_session(cfg, executor=executor,
-                                         source=source)
-    finally:
-        executor.dispatcher.set_mesh(1)
-    return record, summary, tuner.to_entries()
-
-
-def _persist_online(out_dir: str, entries) -> str:
-    """Persist the sweep's online winners to ``<out>/tuned-online.json``.
-
-    Faster-wins merge against the committed cache the sessions were
-    warm-started from: an online entry (interpret-mode batch walls,
-    orders of magnitude above the offline proxy clock) can only *add*
-    keys the committed cache lacks — e.g. sharded widths the router
-    discovered — never displace a committed winner with a
-    wrong-clock measurement.
-    """
-    import os
-
-    from repro.tuning.cache import TuningCache
-
-    online = TuningCache()
-    for entry in entries:
-        online.add(entry)
-    committed = DEFAULT_DISPATCHER.tuning.cache
-    # merge() mutates its receiver, so fold into a copy — the global
-    # dispatcher's committed cache must not grow online entries
-    merged = TuningCache(list(committed) if committed is not None else (),
-                         fingerprint=(committed.fingerprint
-                                      if committed is not None else None))
-    merged.merge(online)
-    return merged.save(os.path.join(out_dir, "tuned-online.json"))
 
 
 if __name__ == "__main__":

@@ -12,7 +12,7 @@ to pick between their MXU and VPU implementations (``engine='auto'``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Optional
 
 from .balance import machine_balance
 from .bounds import best_case_speedup, speedup_overlapped
@@ -30,12 +30,6 @@ class Advice:
     balance_matrix: float
     max_speedup_matrix: float   # tightest paper bound if we used the MXU
     reason: str
-    # tile config the dispatch layer will apply for this decision, as a
-    # hashable sorted (name, value) tuple; None = static defaults.
-    # Attached by Dispatcher.advise from its TuningPolicy, not here:
-    # tile choice is a bandwidth-saturation concern, orthogonal to the
-    # engine decision this class owns.
-    tile_config: Optional[Tuple[Tuple[str, int], ...]] = None
     # how a mesh-configured dispatcher would split this call (a
     # repro.sharding.plan.ShardSpec: kind/num_shards/axis/halo), or
     # None for single-device dispatch.  Attached by Dispatcher.advise

@@ -10,12 +10,6 @@ flatten/pad/tile/unpad plumbing around ``pallas_call``, and (3) the
     advisor, memoizing one ``Advice`` per (kernel, shape, dtype,
     hardware) so steady-state dispatch is a dict hit, not a roofline
     re-derivation.
-  * ``TuningPolicy`` -- consults a versioned ``tuned.json`` cache
-    (``repro.tuning.cache``) for the winning tile configuration per
-    (kernel, engine, dtype, hardware model, shard shape) before falling
-    back to the static tile defaults, so the vector-engine baseline the
-    paper's Eq. 23/24 ceiling is checked against is the
-    *bandwidth-tuned* one.
   * ``elementwise_call`` -- the shared flatten/pad/tile/unpad wrapper and
     block-spec construction for same-shape elementwise kernels (SCALE,
     STREAM Triad, AXPY, ...): a kernel family supplies only its per-tile
@@ -26,16 +20,17 @@ flatten/pad/tile/unpad plumbing around ``pallas_call``, and (3) the
 
 Kernel families register their bodies as an ``EngineOp`` in
 ``repro.kernels.registry``; ``DEFAULT_DISPATCHER.run`` is the single
-path from a registered op + arguments to a Pallas launch.
+path from a registered op + arguments to a Pallas launch.  The tile
+shape is not decided here: each kernel family owns its static default
+(lowered by the kernel's own VMEM budget where it has one), and a
+caller may pass an explicit tile kwarg, which reaches the body as given.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import time
-from typing import (Any, Callable, Dict, Hashable, Mapping, Optional,
-                    Sequence, Tuple)
+from typing import Any, Callable, Dict, Hashable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,14 +43,10 @@ from .advisor import DEFAULT_ADVISOR, Advice, EngineAdvisor
 from .intensity import KernelTraits
 
 __all__ = [
-    "DEFAULT_DISPATCHER", "Dispatcher", "TUNED_CACHE_ENV", "TuningPolicy",
-    "default_cache_key", "elementwise_call", "mxu_dot", "normalize_engine",
-    "use_interpreter",
+    "DEFAULT_DISPATCHER", "Dispatcher", "default_cache_key",
+    "elementwise_call", "mxu_dot", "normalize_engine", "use_interpreter",
     "ELEMENTWISE_BLOCK_ROWS", "ELEMENTWISE_LANES",
 ]
-
-#: Environment variable naming a tuned.json for the default policy.
-TUNED_CACHE_ENV = "REPRO_TUNED_JSON"
 
 _ENGINE_ALIASES = {
     "mxu": "matrix", "matrix": "matrix",
@@ -144,72 +135,12 @@ def default_cache_key(*args, **kwargs) -> Hashable:
 
 
 def _dtype_of(args: tuple, kwargs: dict) -> Optional[str]:
-    """The dtype string of the first array-ish call argument, if any.
-
-    Tile configs are cached per (kernel, engine, dtype, hw): dtype is
-    part of the bandwidth story (bytes moved per element), so it is
-    resolved from the live arguments the same way ``_probe`` sees them.
-    """
+    """The dtype string of the first array-ish call argument, if any
+    (the traced launch's roofline counters are per dtype)."""
     for x in list(args) + list(kwargs.values()):
         if hasattr(x, "dtype") and hasattr(x, "shape"):
             return str(x.dtype)
     return None
-
-
-class TuningPolicy:
-    """Tile-configuration lookups against a ``tuned.json`` cache.
-
-    The policy layer between the dispatcher and
-    ``repro.tuning.cache.TuningCache``: ``lookup`` returns the winning
-    tile params for (kernel, engine, dtype, hw model) or None, in which
-    case callers use the static defaults.  The default policy lazily
-    loads the path named by :data:`TUNED_CACHE_ENV` (forgivingly — a
-    corrupt or version-mismatched file warns and degrades to static
-    defaults rather than breaking dispatch).
-    """
-
-    def __init__(self, cache=None, path: Optional[str] = None):
-        self._cache = cache
-        self._path = path
-        self._resolved = cache is not None
-
-    @property
-    def cache(self):
-        """The backing TuningCache (lazy-loaded), or None if empty."""
-        if not self._resolved:
-            path = self._path or os.environ.get(TUNED_CACHE_ENV)
-            if path:
-                from ..tuning.cache import TuningCache
-                self._cache = TuningCache.load_or_warn(path)
-            self._resolved = True
-        return self._cache
-
-    def load(self, path: str) -> None:
-        """Point the policy at a tuned.json (forgiving load, see above)."""
-        from ..tuning.cache import TuningCache
-        self._cache = TuningCache.load_or_warn(path)
-        self._resolved = True
-
-    def set_cache(self, cache) -> None:
-        """Install an in-memory TuningCache (None = static defaults)."""
-        self._cache = cache
-        self._resolved = True
-
-    def lookup(self, kernel: str, engine: str, dtype: Optional[str],
-               hw_model: str, num_shards: int = 1):
-        """The TunedEntry for this key, or None (use static defaults).
-
-        ``num_shards`` scopes the lookup to the launch width via the
-        cache's ``shard_shape`` key component: a sharded launch only
-        ever sees per-shard winners, never the full-width tile
-        (the schema-1 collision the 5-field key fixed).
-        """
-        cache = self.cache
-        if cache is None or dtype is None:
-            return None
-        from ..tuning.cache import shard_shape_of
-        return cache.lookup(kernel, engine, dtype, hw_model,
-                            shard_shape_of(num_shards))
 
 
 _MESH_MODES = ("virtual", "mesh")
@@ -232,10 +163,8 @@ class Dispatcher:
     """
 
     def __init__(self, advisor: Optional[EngineAdvisor] = None,
-                 tuning: Optional[TuningPolicy] = None,
                  mesh_shards: int = 1, mesh_mode: str = "virtual"):
         self.advisor = advisor if advisor is not None else DEFAULT_ADVISOR
-        self.tuning = tuning if tuning is not None else TuningPolicy()
         self._mesh_shards = max(1, int(mesh_shards))
         self._mesh_mode = _check_mesh_mode(mesh_mode)
         self._cache: Dict[Hashable, Advice] = {}
@@ -300,10 +229,7 @@ class Dispatcher:
 
         The cache key is (kernel, hardware, shapes/dtypes/static params);
         the op's ``KernelTraits`` factory (W flops, Q bytes per Eq. 2)
-        only runs on a miss.  The returned Advice also records the tile
-        config the TuningPolicy would apply for the chosen engine
-        (``tile_config=None`` means static defaults), so BENCH records
-        and the claims report can say *which* tiles produced a number.
+        only runs on a miss.
         """
         key_fn = op.cache_key or default_cache_key
         key = (op.name, self.hw.name, self._mesh_shards,
@@ -311,14 +237,6 @@ class Dispatcher:
 
         def make() -> Advice:
             advice = self.advisor.advise(op.traits(*args, **kwargs))
-            entry = self.tuning.lookup(op.name, advice.engine,
-                                       _dtype_of(args, kwargs),
-                                       self.hw.name,
-                                       num_shards=self._mesh_shards)
-            if entry is not None:
-                advice = dataclasses.replace(
-                    advice,
-                    tile_config=tuple(sorted(entry.params.items())))
             if self._mesh_shards > 1:
                 # planned once per (kernel, shape, mesh) and memoized
                 # with the engine decision: steady-state sharded
@@ -356,93 +274,39 @@ class Dispatcher:
             return forced
         return self.advise(op, *args, **kwargs).engine
 
-    def tile_params(self, op, eng: str, *args,
-                    **kwargs) -> Optional[Dict[str, int]]:
-        """The tuned tile params this call would use, or None (defaults).
-
-        Consults the TuningPolicy with the op's name, the resolved
-        engine, the call's dtype, the advisor's hardware model, and the
-        current mesh width -- the granularity winners are cached at.
-        """
-        entry = self.tuning.lookup(op.name, eng, _dtype_of(args, kwargs),
-                                   self.hw.name,
-                                   num_shards=self._mesh_shards)
-        return dict(entry.params) if entry is not None else None
-
     def run(self, op, *args, engine: str = "auto",
-            interpret: Optional[bool] = None,
-            tile_config: Optional[Mapping[str, int]] = None, **kwargs):
-        """Advisor-route (paper §6), tile-tune, and launch one op.
+            interpret: Optional[bool] = None, **kwargs):
+        """Advisor-route (paper §6) and launch one op.
 
-        Tile precedence: an explicit ``tile_config`` argument overrides
-        everything (including per-call kwargs it collides with); a
-        TuningPolicy hit overrides the static defaults but *not*
-        explicitly passed kwargs; otherwise the family's static
-        defaults apply.  Config keys are validated against the op's
-        declared ``tile_space`` so a stale cache cannot smuggle unknown
-        kwargs into a kernel launch.
+        ``kwargs`` reach the engine body as given, tile kwargs
+        (``block_rows``, ``block_s``, ...) included: the family's body
+        owns its tile, and its traits factory ignores tile kwargs.
 
         Every call opens two :mod:`repro.obs` spans, which a profiler
-        trace shows as ``dispatch.route`` (engine resolution and tile
-        lookup) and ``dispatch.launch`` (the engine body: the wrapper's
-        eager ops and the Pallas enqueue); neither blocks.  When the
-        in-memory tracer is enabled, the call is also wrapped in a
-        ``dispatch`` span, and the launch span blocks on the result and
-        carries the Eq. 2/3/4 roofline counters for the measured wall
-        time.
+        trace shows as ``dispatch.route`` (engine resolution) and
+        ``dispatch.launch`` (the engine body: the wrapper's eager ops
+        and the Pallas enqueue); neither blocks.  When the in-memory
+        tracer is enabled, the call is also wrapped in a ``dispatch``
+        span, and the launch span blocks on the result and carries the
+        Eq. 2/3/4 roofline counters for the measured wall time.
         """
         if not TRACER.enabled:
             return self._run(op, *args, engine=engine, interpret=interpret,
-                             tile_config=tile_config, **kwargs)
+                             **kwargs)
         with TRACER.span("dispatch", layer="dispatch",
                          kernel=op.name) as span_attrs:
             return self._run(op, *args, engine=engine, interpret=interpret,
-                             tile_config=tile_config,
                              _span_attrs=span_attrs, **kwargs)
 
     def _run(self, op, *args, engine: str, interpret: Optional[bool],
-             tile_config: Optional[Mapping[str, int]],
              _span_attrs: Optional[Dict[str, Any]] = None, **kwargs):
         with TRACER.span("route", layer="dispatch", kernel=op.name):
-            # tile params never move a kernel on the roofline: strip them
-            # before the advise path so traits factories only see semantic
-            # kwargs, then re-apply them for the launch itself
-            semantic = {k: v for k, v in kwargs.items()
-                        if k not in op.tile_space}
-            eng = self.resolve(op, *args, engine=engine, **semantic)
+            eng = self.resolve(op, *args, engine=engine, **kwargs)
             fn = op.engines.get(eng)
             if fn is None:
                 raise ValueError(
                     f"kernel {op.name!r} has no {eng!r} variant "
                     f"(has {sorted(op.engines)})")
-            explicit = tile_config is not None
-            cfg = dict(tile_config) if explicit else \
-                self.tile_params(op, eng, *args, **semantic)
-            if cfg:
-                unknown = sorted(set(cfg) - set(op.tile_space))
-                if unknown and explicit:
-                    raise ValueError(
-                        f"kernel {op.name!r} does not accept tile "
-                        f"parameter(s) {unknown}; its tile space is "
-                        f"{sorted(op.tile_space) or 'empty'}")
-                if unknown:
-                    # a stale cache entry is advisory, never a crash: keep
-                    # the params this build still knows, warn about the rest
-                    import warnings
-
-                    from ..tuning.cache import TuningCacheWarning
-                    warnings.warn(
-                        f"tuned config for {op.name}/{eng} names unknown "
-                        f"tile parameter(s) {unknown}; ignoring them "
-                        f"(tile space: {sorted(op.tile_space) or 'empty'})",
-                        TuningCacheWarning, stacklevel=2)
-                    cfg = {k: v for k, v in cfg.items()
-                           if k in op.tile_space}
-                if explicit:
-                    kwargs = {**kwargs, **cfg}
-                else:  # tuned values fill gaps; a None kwarg is a gap too
-                    kwargs = {**kwargs, **{k: v for k, v in cfg.items()
-                                           if kwargs.get(k) is None}}
         if _span_attrs is None:
             with TRACER.span("launch", layer="dispatch", kernel=op.name):
                 return fn(*args, interpret=interpret, **kwargs)
@@ -458,27 +322,13 @@ class Dispatcher:
             jax.block_until_ready(out)
             dur_us = (time.perf_counter() - t0) * 1e6
             try:
-                sample = roofline_sample(op.traits(*args, **semantic),
+                sample = roofline_sample(op.traits(*args, **kwargs),
                                          self.hw, eng, dtype, dur_us)
                 launch_attrs.update(sample.as_attrs())
             except (TypeError, ValueError) as e:
                 LOG.debug("roofline counters unavailable",
                           kernel=op.name, engine=eng, error=str(e))
         return out
-
-    def load_tuned(self, path: str) -> None:
-        """Adopt a tuned.json and invalidate memoized Advice.
-
-        The Advice cache embeds tile configs, so swapping caches must
-        drop it -- otherwise stale configs keep reporting.
-        """
-        self.tuning.load(path)
-        self.cache_clear()
-
-    def set_tuning_cache(self, cache) -> None:
-        """Install an in-memory TuningCache (None = static defaults)."""
-        self.tuning.set_cache(cache)
-        self.cache_clear()
 
     def cache_info(self) -> Dict[str, int]:
         """Advice-cache statistics: {size, hits, misses}."""
@@ -535,9 +385,8 @@ def elementwise_call(body: Callable, arrays: Sequence[jnp.ndarray],
     grid/block-spec construction -> unpad round trip that every
     elementwise kernel family previously duplicated.
 
-    ``block_rows``/``lanes`` are the tunable tile shape; ``None`` means
-    the static defaults (the autotuner in ``repro.tuning`` searches
-    this space and the dispatch layer passes winners down per call).
+    ``block_rows``/``lanes`` are the tile shape; ``None`` means the
+    static 256 x 1024 default.
     """
     lanes = ELEMENTWISE_LANES if lanes is None else int(lanes)
     block_rows = (ELEMENTWISE_BLOCK_ROWS if block_rows is None

@@ -1,10 +1,7 @@
 """Canonical wall-time measurement: median + IQR over warmed iterations.
 
-One implementation serves both measurement consumers — the benchmark
-harness (``benchmarks.common`` re-exports these names) and the tile
-autotuner (``repro.tuning.tuner``) — so the statistics behind
-``ref_us_per_call`` and behind tuned-vs-default deltas can never
-drift apart.
+The benchmark harness (``benchmarks.common`` re-exports these names)
+measures ``ref_us_per_call`` with it.
 
 When the :mod:`repro.obs` tracer is enabled, every timed iteration is
 also emitted as a wall-clock span *after* the measurement loop, with

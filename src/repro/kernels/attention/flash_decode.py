@@ -23,8 +23,8 @@ live block are masked, in the scores and in V, since a block that runs
 past S reads padding.
 
 ``block_s`` is capped so the double-buffered K and V blocks fit
-``VMEM_BLOCK_BUDGET`` at the given KH, Dh and itemsize; a requested or
-tuned ``block_s`` may lower the length, never raise it past that cap.
+``VMEM_BLOCK_BUDGET`` at the given KH, Dh and itemsize; a requested
+``block_s`` may lower the length, never raise it past that cap.
 """
 from __future__ import annotations
 

@@ -36,7 +36,9 @@ class EngineOp:
     'vector'/'matrix' to ``fn(*args, interpret=..., **kw)``;
     ``traits``/``reference``/``make_inputs`` share the op's call
     signature so the dispatch layer, the generic benchmark driver, and
-    the registry tests need no per-kernel knowledge.
+    the registry tests need no per-kernel knowledge.  ``traits`` ignores
+    the tile kwargs an engine body accepts: a tile never moves a kernel
+    on the roofline.
     """
 
     name: str
@@ -50,18 +52,6 @@ class EngineOp:
     test_size: int = 0
     cache_key: Optional[Callable[..., Hashable]] = None
     doc: str = ""
-    # -- autotuning opt-in (see repro.tuning / docs/tuning.md) ----------
-    # tile parameter name -> candidate values; empty = not tunable.
-    # Every engine entry point must accept each name as a keyword.
-    tile_space: Mapping[str, Tuple[int, ...]] = dataclasses.field(
-        default_factory=dict)
-    # the static default per tile parameter (what untuned dispatch uses;
-    # anchors the tuner's tuned-vs-default delta)
-    tile_defaults: Mapping[str, int] = dataclasses.field(
-        default_factory=dict)
-    # (params, *args, **kwargs) -> pure-XLA computation honoring the tile
-    # params: the off-hardware timing stand-in (repro.tuning.proxy)
-    tune_proxy: Optional[Callable[..., Any]] = None
     # -- mesh sharding (see repro.sharding / docs/sharding.md) ----------
     # how this family splits across a data-axis mesh: 'data'
     # (flattened elementwise ranges), 'rowblock' (contiguous row /
@@ -74,18 +64,15 @@ class EngineOp:
     shard_halo: Optional[Callable[..., int]] = None
 
     def __call__(self, *args, engine: str = "auto",
-                 interpret: Optional[bool] = None,
-                 tile_config: Optional[Mapping[str, int]] = None,
-                 **kwargs):
+                 interpret: Optional[bool] = None, **kwargs):
         """Launch via the default dispatcher ('auto' = paper §6 routing).
 
-        ``tile_config`` forces a tile configuration for this call;
-        omitted, the dispatcher consults its TuningPolicy and then the
-        family's static defaults.
+        A tile kwarg (``block_rows``, ``lanes``, ``block_s``) is passed
+        to the engine body as given; omitted, the body uses its
+        family's static default.
         """
         return DEFAULT_DISPATCHER.run(self, *args, engine=engine,
-                                      interpret=interpret,
-                                      tile_config=tile_config, **kwargs)
+                                      interpret=interpret, **kwargs)
 
     def advice(self, *args, **kwargs):
         """The memoized §6 Advice (engine, boundedness, Eq. 23/24 ceiling)."""

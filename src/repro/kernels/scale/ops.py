@@ -7,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.intensity import scale as scale_traits
-from ...tuning.proxy import tiled_elementwise
-from ..elementwise_tuning import ELEMENTWISE_TILE_DEFAULTS, ELEMENTWISE_TILE_SPACE
 from ..registry import EngineOp, register
 from .ref import scale_ref
 from .scale import scale_matrix, scale_vector
@@ -16,23 +14,14 @@ from .scale import scale_matrix, scale_vector
 __all__ = ["SCALE_OP", "scale"]
 
 
-def _traits(b, q):
-    del q
+def _traits(b, q, *, block_rows=None, lanes=None):
+    del q, block_rows, lanes
     return scale_traits(b.size, dsize=b.dtype.itemsize)
 
 
 def _make_inputs(rng: np.random.Generator, size: int, dtype: str = "float32"):
     b = jnp.asarray(rng.standard_normal(size), dtype)
     return (b, 1.5), {}
-
-
-def _proxy_body(scalars, b):
-    return (scalars[0] * b).astype(b.dtype)
-
-
-def _tune_proxy(params, b, q):
-    """Pure-XLA tiled a = q*b for off-hardware candidate timing."""
-    return tiled_elementwise(_proxy_body, (b,), (q,), **params)
 
 
 SCALE_OP = register(EngineOp(
@@ -45,9 +34,6 @@ SCALE_OP = register(EngineOp(
     dtypes=("float32", "bfloat16"),
     test_size=300_000,
     doc="STREAM SCALE a = q*b; I = 1/(2D), memory-bound everywhere",
-    tile_space=ELEMENTWISE_TILE_SPACE,
-    tile_defaults=ELEMENTWISE_TILE_DEFAULTS,
-    tune_proxy=_tune_proxy,
 ))
 
 

@@ -1,11 +1,8 @@
 """Public SpMV op (block-ELL), registered as an ``EngineOp``.
 
-SpMV declares no ``tile_space``: its (bm, bn) blocking is baked into
-the BlockEll *data layout* by ``dense_to_bell``, so a per-call tile
-config cannot re-block the caller's matrix.  The dispatch layer still
-accepts (and validates) ``tile_config`` for this op — an explicit
-config naming any parameter fails fast with the op's empty space, and
-retiling is done where the layout is built.
+SpMV takes no tile kwarg: its (bm, bn) blocking is baked into the
+BlockEll *data layout* by ``dense_to_bell``, so retiling is done where
+the layout is built.
 """
 from __future__ import annotations
 

@@ -2,10 +2,8 @@
 aware: the advisor sees the blocked intensity I_t = t*|S|/D)."""
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -19,12 +17,6 @@ __all__ = ["STENCIL_OP", "stencil", "suite", "TABLE3_DEPTH", "StencilSpec"]
 
 #: Static leading-axis block height (``stencil_apply``'s default).
 DEFAULT_BLOCK_ROWS = 128
-
-#: Leading-axis block heights the autotuner may try.  The halo grows
-#: with temporal depth (t * r rows re-read per block edge), so the
-#: sweet spot shifts with ``steps`` — exactly why this is tuned, not
-#: hardcoded.
-STENCIL_TILE_SPACE = {"block_rows": (32, 64, 128, 256)}
 
 
 def _traits(u, spec: StencilSpec, *, steps: int = 1, block_rows=None):
@@ -54,42 +46,6 @@ def _engine_fn(engine: str):
     return call
 
 
-@functools.partial(
-    jax.jit, static_argnames=("spec", "steps", "block_rows"))
-def _blocked_stencil_jnp(u: jnp.ndarray, spec: StencilSpec, *,
-                         steps: int, block_rows: int) -> jnp.ndarray:
-    """Pure-jnp leading-axis blocked pipeline (the timing proxy).
-
-    Each block of ``block_rows`` rows reads a ``t*r`` halo on both sides
-    and runs ``t`` fused reference steps with the domain re-masked after
-    each -- the trapezoid of ``stencil_apply``, unrolled as XLA ops, so
-    its CPU wall time tracks the block count and halo recompute.
-    """
-    halo = steps * spec.radius
-    n = u.shape[0]
-    n_tiles = -(-n // block_rows)
-    lead = [(halo, n_tiles * block_rows - n + halo)]
-    up = jnp.pad(u, lead + [(0, 0)] * (u.ndim - 1))
-    rows = jnp.arange(block_rows + 2 * halo)
-    out_blocks = []
-    for i in range(n_tiles):
-        tile = up[i * block_rows:(i + 1) * block_rows + 2 * halo]
-        g = i * block_rows - halo + rows
-        mask = ((g >= 0) & (g < n)).astype(u.dtype)
-        mask = mask.reshape((-1,) + (1,) * (u.ndim - 1))
-        for _ in range(steps):
-            tile = stencil_ref(tile, spec) * mask
-        out_blocks.append(tile[halo:halo + block_rows])
-    return jnp.concatenate(out_blocks, axis=0)[:n]
-
-
-def _tune_proxy(params, u, spec: StencilSpec, *, steps: int = 1,
-                block_rows=None):
-    br = int(params.get("block_rows",
-                        block_rows or DEFAULT_BLOCK_ROWS))
-    return _blocked_stencil_jnp(u, spec, steps=steps, block_rows=br)
-
-
 STENCIL_OP = register(EngineOp(
     name="stencil",
     traits=_traits,
@@ -102,9 +58,6 @@ STENCIL_OP = register(EngineOp(
     bench_sizes=(128, 256),
     test_size=48,
     doc="|S|-point stencil, t fused steps; I_t = t*|S|/D (paper Eq. 13)",
-    tile_space=STENCIL_TILE_SPACE,
-    tile_defaults={"block_rows": DEFAULT_BLOCK_ROWS},
-    tune_proxy=_tune_proxy,
     # mesh split: leading-axis row blocks; t fused steps at radius r
     # need t*r halo rows from each neighbour (the Eq. 13 trapezoid),
     # which the sharding layer slices in and crops back out
@@ -121,8 +74,8 @@ def stencil(u: jnp.ndarray, spec: StencilSpec, *, steps: int = 1,
     'auto' consults the advisor with the *temporally blocked* intensity
     I_t = t * |S| / D (paper Eq. 13): shallow blocking stays memory-bound
     (vector engine), deep blocking can cross the knee.  ``block_rows``
-    is the leading-axis tile height; None lets the dispatch layer apply
-    a tuned value (or the static default of 128).
+    is the leading-axis tile height; None means the static default of
+    128, which the kernel lowers to fit its VMEM budget.
     """
     kwargs = {} if block_rows is None else {"block_rows": block_rows}
     return STENCIL_OP(u, spec, steps=steps, engine=engine,

@@ -19,8 +19,7 @@ Design points:
   regardless of level — the test-friendly replacement for
   ``pytest.warns`` on what used to be bare warnings.
 
-Typed warnings with load-bearing semantics (e.g.
-``repro.tuning.cache.TuningCacheWarning``) stay warnings: callers
+Typed warnings with load-bearing semantics stay warnings: callers
 filter them by type, which a log line cannot offer.
 """
 from __future__ import annotations

@@ -8,8 +8,9 @@ into typed rows.  Five file schemas are accepted:
   "records": [...]}`` with environment metadata (jax version, device
   kind, interpret flag, hardware model),
 * schema 3 -- schema 2 plus an optional per-record ``tile_config``
-  (the tuned tile params a sweep point launched with, and the tuner's
-  tuned-vs-default timings; null = static tile defaults),
+  (the tile params a sweep point launched with, and a CPU-proxy
+  tuned-vs-default timing, from records written while the repo still
+  carried a tile autotuner; null = static tile defaults),
 * schema 4 -- **serving** record sets (``"kind": "serving"``) from
   ``python -m benchmarks.run serve``: one :class:`ServingRecord` per
   (kernel, engine, workload, size, dtype) session with latency
@@ -81,9 +82,9 @@ class BenchRecord:
     pred_us_v5e: Optional[float] = None  # Q / mem_bw analytic floor
     iqr_us: Optional[float] = None       # timing spread (schema 2)
     iters: Optional[int] = None          # timing iterations (schema 2)
-    # schema 3: tuned tile params + tuner timings ({"params": {...},
-    # "tuned_us": ..., "default_us": ..., "source": ...}); None means
-    # the launch used the family's static tile defaults
+    # schema 3: tile params + the old autotuner's timings ({"params":
+    # {...}, "tuned_us": ..., "default_us": ..., "source": ...}), kept
+    # as data; None means the family's static tile defaults
     tile_config: Optional[Mapping[str, Any]] = None
     # schema 5: the mesh the point was swept under ([N]) and the shard
     # plan + traffic accounting it executed with; None = single device
@@ -130,24 +131,6 @@ class BenchRecord:
         """
         return (self.kernel, self.engine, self.size, self.dtype,
                 self.mesh_devices)
-
-    @property
-    def tile_params(self) -> Optional[Mapping[str, int]]:
-        """The tuned tile params this point launched with, if any."""
-        if not self.tile_config:
-            return None
-        return self.tile_config.get("params")
-
-    @property
-    def tuned_speedup(self) -> Optional[float]:
-        """Tuner-measured default_us / tuned_us for this point's config."""
-        if not self.tile_config:
-            return None
-        tuned = self.tile_config.get("tuned_us")
-        default = self.tile_config.get("default_us")
-        if not tuned or not default or tuned <= 0:
-            return None
-        return float(default) / float(tuned)
 
 
 _SERVING_REQUIRED = (
@@ -230,36 +213,19 @@ class ServingRecord:
     # ({"clock": "virtual", "batch_spans", "span_compute_ms",
     # "log_compute_ms", chaos instant counts}); None = legacy session
     trace: Optional[Mapping[str, Any]] = None
-    # online-tuned sessions only: the bandit + router block ({"mode":
-    # "online", "budget", "keys": {key: {arms, events, ...}}, optional
-    # "router"}) whose decisions the online_ceiling claim replays
-    # against Eq. 23/24; None for statically-tuned sessions
-    tuning: Optional[Mapping[str, Any]] = None
 
     @property
-    def tuning_mode(self) -> str:
-        """'online' when the session carried a tuning block, else
-        'static' — part of the session key so an adaptively-tuned p99
-        never gates against a statically-tuned baseline."""
-        if not self.tuning:
-            return "static"
-        return str(self.tuning.get("mode", "online"))
-
-    @property
-    def point(self) -> Tuple[str, str, str, int, str, int, str]:
-        """Session key (kernel, engine, workload, size, dtype, shards,
-        tuning mode) — what the ``benchmarks/compare.py`` p99/goodput
-        gate joins on.
+    def point(self) -> Tuple[str, str, str, int, str, int]:
+        """Session key (kernel, engine, workload, size, dtype, shards)
+        — what the ``benchmarks/compare.py`` p99/goodput gate joins on.
 
         The mesh width is part of the key (legacy records without one
         key as 1) so a sharded session never gates against — or
         silently shadows — the single-device baseline when both live
-        in one records directory; the tuning mode (``'static'`` /
-        ``'online'``) separates adaptively-tuned sessions from their
-        static baselines the same way.
+        in one records directory.
         """
         return (self.kernel, self.engine, self.workload, self.size,
-                self.dtype, self.num_shards or 1, self.tuning_mode)
+                self.dtype, self.num_shards or 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -390,16 +356,6 @@ def _to_serving_record(raw: Mapping[str, Any], path: str) -> ServingRecord:
             raise ValueError(f"{path}: events must be an object with "
                              f"a 'log' list, got {events!r}")
         events = dict(events)
-    tuning = raw.get("tuning")
-    if tuning is not None:
-        needed = ("mode", "budget", "keys")
-        if not isinstance(tuning, Mapping) or \
-                any(k not in tuning for k in needed) or \
-                not isinstance(tuning.get("keys"), Mapping):
-            raise ValueError(f"{path}: tuning must be an object with "
-                             f"{needed} fields (keys a map), got "
-                             f"{tuning!r}")
-        tuning = dict(tuning)
     trace = _check_trace(raw.get("trace"), path)
     return ServingRecord(
         kernel=str(raw["kernel"]),
@@ -438,7 +394,6 @@ def _to_serving_record(raw: Mapping[str, Any], path: str) -> ServingRecord:
         phases=(dict(phases) if phases is not None else None),
         verdict=verdict,
         events=events,
-        tuning=tuning,
         trace=trace,
         **{k: (float(v) if v is not None else None)
            for k, v in opt.items()},

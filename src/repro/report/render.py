@@ -22,12 +22,6 @@ Turns loaded record sets + claim results into:
   sub-table: wall time of the one ``shard_map`` program, the isolated
   ``ppermute``-ring cost of its halo exchange, and the skew against
   the virtual max-over-shards clock,
-* an **online tuning** section (records with a ``tuning`` payload from
-  ``serve --online-tune``): per-session bandit decisions, regret
-  against the running best, and the router's width trajectory, all
-  replayed by the ``online_ceiling`` claim — plus per-key bandit
-  tables and the router decision log on
-  ``docs/benchmarks/<kernel>-serving-online.md`` pages,
 * an **observability** section (schema-7 ``trace`` blocks): the
   per-(kernel, engine) roofline gauge — achieved GB/s against the
   Eq. 4 bound and achieved FLOP/s against the Eq. 3 ceiling, as
@@ -43,12 +37,11 @@ from __future__ import annotations
 
 import os
 import statistics
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 from ..core.balance import machine_balance
-from ..core.hw import HardwareSpec
-from .claims import (CLAIMS, SERVING_CLAIMS, ClaimResult, ceiling_bound,
-                     check_record, check_serving_record, hw_for)
+from .claims import (CLAIMS, ClaimResult, ceiling_bound, check_record,
+                     check_serving_record, hw_for)
 from .records import BenchRecord, RecordSet, ServingRecord
 
 __all__ = ["page_name", "render_kernel_page", "render_report",
@@ -71,24 +64,15 @@ def _fmt(x, digits: int = 4) -> str:
 def page_name(rs: RecordSet) -> str:
     """The docs/benchmarks/ page filename for one record set.
 
-    Serving sets get a ``-serving`` suffix, online-tuned serving sets
-    (every record carries a ``tuning`` payload) ``-serving-online``,
-    mesh sets a ``-mesh<N>`` suffix (composable: a mesh serving sweep
+    Serving sets get a ``-serving`` suffix, mesh sets a ``-mesh<N>``
+    suffix (composable: a mesh serving sweep
     is ``<kernel>-serving-mesh<N>.md``), so one kernel family's
     evidence pages never collide.
     """
     suffix = "-serving" if rs.kind == "serving" else ""
-    if _is_online(rs):
-        suffix += "-online"
     if rs.mesh_devices > 1:
         suffix += f"-mesh{rs.mesh_devices}"
     return f"{rs.kernel}{suffix}.md"
-
-
-def _is_online(rs: RecordSet) -> bool:
-    """True when the set holds online-tuned sessions (tuning payloads)."""
-    return rs.kind == "serving" and \
-        any(rec.tuning for rec in rs.records)
 
 
 def _set_label(rs: RecordSet) -> str:
@@ -96,8 +80,6 @@ def _set_label(rs: RecordSet) -> str:
     parts = []
     if rs.kind == "serving":
         parts.append("serving")
-    if _is_online(rs):
-        parts.append("online")
     if rs.mesh_devices > 1:
         parts.append(f"mesh {rs.mesh_devices}")
     return rs.kernel + (f" ({', '.join(parts)})" if parts else "")
@@ -113,22 +95,6 @@ def _check_set(rs: RecordSet) -> List[Tuple[BenchRecord,
 def _claim_cell(results: Sequence[ClaimResult], claim: str) -> str:
     fails = sum(1 for r in results if r.claim == claim and not r.passed)
     return "0 ✅" if fails == 0 else f"{fails} ❌"
-
-
-def _tile_cell(rec: BenchRecord) -> str:
-    """'block_rows=128, lanes=512' for a tuned point, '—' for defaults."""
-    params = rec.tile_params
-    if not params:
-        return "—"
-    return ", ".join(f"{k}={v}" for k, v in sorted(params.items()))
-
-
-def _tuned_delta_cell(rec: BenchRecord) -> str:
-    """Tuner-measured gain of the tuned tiles over the static defaults."""
-    speedup = rec.tuned_speedup
-    if speedup is None:
-        return "—"
-    return f"{(speedup - 1.0) * 100:+.1f}%"
 
 
 def render_report(recsets: Sequence[RecordSet]) -> str:
@@ -197,36 +163,12 @@ def render_report(recsets: Sequence[RecordSet]) -> str:
         add(f"**{worst} claim violation(s) across {total_records} "
             "records — see per-kernel pages.**")
     add("")
-    tuned = [(rs, rec) for rs in bench for rec in rs.records
-             if rec.tile_config]
-    if tuned:
-        add("## Tuned tile configurations")
-        add("")
-        add("Sweep points launched with autotuned tiles "
-            "(`python -m benchmarks.run tune`); the delta is the "
-            "tuner's own tuned-vs-default wall-time measurement, per "
-            "(kernel, engine, dtype) — the bandwidth-saturation "
-            "tightening the Eq. 23/24 check rides on.")
-        add("")
-        add("| kernel | engine | dtype | tile config | tuned Δ vs "
-            "default |")
-        add("|---|---|---|---|---|")
-        seen = set()
-        for rs, rec in tuned:
-            key = (rec.kernel, rec.engine, rec.dtype)
-            if key in seen:
-                continue
-            seen.add(key)
-            add(f"| {rec.kernel} | {rec.engine} | {rec.dtype} | "
-                f"{_tile_cell(rec)} | {_tuned_delta_cell(rec)} |")
-        add("")
     if sharded:
         lines.extend(_sharded_section(sharded, bench))
     if serving:
         lines.extend(_serving_section(serving))
         lines.extend(_failure_section(serving))
         lines.extend(_verdict_section(serving))
-        lines.extend(_online_section(serving))
     lines.extend(_observability_section(recsets))
     add("## Methodology")
     add("")
@@ -240,10 +182,6 @@ def render_report(recsets: Sequence[RecordSet]) -> str:
         "Eq. 17 (fully overlapped, 1.0x) under the default overlap "
         "assumption, never above Eq. 23 (2 − 2/(1+α)) or Eq. 24 "
         "(1 + I/B).")
-    add("- `tile config` columns show the autotuned tile parameters a "
-        "point launched with (`—` = static defaults); deltas come from "
-        "the tuner's pure-XLA proxy timings, never from interpret-mode "
-        "Pallas (whose wall times the cache refuses to persist).")
     add("- Serving sessions run on a virtual clock: arrivals are seeded "
         "and replayable, batch compute is measured wall time folded "
         "back into the clock — so queueing compounds under load, but "
@@ -620,84 +558,6 @@ def _verdict_section(serving: Sequence[RecordSet]) -> List[str]:
     return lines
 
 
-def _online_section(serving: Sequence[RecordSet]) -> List[str]:
-    """The REPORT.md online-tuning block (records with ``tuning``).
-
-    One row per ``serve --online-tune`` session: how many bandit keys
-    the session tuned, how many decisions it made, the total regret
-    against the running best (the price of exploration, in µs of batch
-    compute), the router's width trajectory when ``--slo-route`` was
-    on, and the session's p99 against the statically-tuned baseline of
-    the same (kernel, workload, size, dtype) config — adaptivity must
-    pay for itself at the tail.  The ``online_ceiling`` claim replays
-    every decision and holds the Eq. 23/24 line: a bandit may tune
-    tiles, never route memory-bound work onto the matrix engine.
-    """
-    rows = [(rec, crs) for rs in serving for rec, crs in _check_set(rs)
-            if rec.tuning]
-    if not rows:
-        return []
-    static_p99: Dict[Tuple, float] = {}
-    for rs in serving:
-        for rec in rs.records:
-            if not rec.tuning:
-                key = (rec.kernel, rec.workload, rec.size, rec.dtype,
-                       rec.engine)
-                static_p99[key] = rec.p99_ms
-    lines: List[str] = []
-    add = lines.append
-    add("## Online tuning")
-    add("")
-    add("Sessions from `python -m benchmarks.run serve --online-tune "
-        "[--slo-route]`: a budgeted UCB bandit over each family's "
-        "declared `tile_space` re-tunes tile shapes from measured batch "
-        "compute inside the virtual clock, warm-started from the "
-        "committed `tuned.json`; with `--slo-route`, shard width and "
-        "exploration follow queue depth and SLO headroom instead of "
-        "the roofline alone. The `online_ceiling` claim replays every "
-        "recorded decision byte-identically and re-checks Eq. 23/24 on "
-        "each one — an adaptive router never \"discovers\" a "
-        "matrix-engine win the ceiling forbids.")
-    add("")
-    add("| kernel | workload | engine | keys | decisions | regret µs | "
-        "router widths | p99 ms | static p99 ms | goodput /s | claims |")
-    add("|---|---|---|---|---|---|---|---|---|---|---|")
-    fails = 0
-    for rec, crs in rows:
-        t = dict(rec.tuning)
-        fails += sum(1 for c in crs if not c.passed)
-        widths = [int(d.get("width", 1)) for d in
-                  dict(t.get("router") or {}).get("decisions", [])]
-        trajectory = "—"
-        if widths:
-            hops = [widths[0]]
-            for w in widths[1:]:
-                if w != hops[-1]:
-                    hops.append(w)
-            trajectory = "→".join(str(w) for w in hops)
-        baseline = static_p99.get((rec.kernel, rec.workload, rec.size,
-                                   rec.dtype, rec.engine))
-        add("| " + " | ".join([
-            rec.kernel, rec.workload, rec.engine,
-            str(len(dict(t.get("keys", {})))),
-            _fmt(t.get("decisions")), _fmt(t.get("regret_us_total")),
-            trajectory, _fmt(rec.p99_ms), _fmt(baseline),
-            _fmt(rec.goodput_rps), _serving_claim_verdict(crs),
-        ]) + " |")
-    add("")
-    if fails == 0:
-        add(f"**{len(rows)} online-tuned sessions; zero claim "
-            "violations.** Adaptivity changes tiles and shard width, "
-            "never the verdict: every bandit key and every router "
-            "decision stayed on the engine Eq. 23/24 prescribes, and "
-            "the recorded decision sequences replay exactly.")
-    else:
-        add(f"**{fails} claim violation(s) across {len(rows)} "
-            "online-tuned sessions — see per-kernel serving pages.**")
-    add("")
-    return lines
-
-
 def _observability_section(recsets: Sequence[RecordSet]) -> List[str]:
     """The REPORT.md observability block (schema-7 ``trace`` records).
 
@@ -805,14 +665,10 @@ def _engine_pairs(serving: Sequence[RecordSet]):
     """(key, (vector record, matrix record)) pairs for the same session
     config served under both forced engines, sorted by key.  The mesh
     width is part of the key so a sharded session never pairs against
-    the single-device run of the other engine.  Online-tuned sessions
-    are excluded — their engine comes from ``auto``, so they would
-    shadow the forced-vector leg of the same config."""
+    the single-device run of the other engine."""
     by_key: Dict[Tuple, Dict[str, ServingRecord]] = {}
     for rs in serving:
         for rec in rs.records:
-            if rec.tuning:
-                continue
             key = (rec.kernel, rec.workload, rec.size, rec.dtype,
                    rec.num_shards or 1)
             by_key.setdefault(key, {})[rec.engine] = rec
@@ -883,62 +739,6 @@ def render_serving_page(rs: RecordSet) -> str:
                 _fmt(o.get("bytes_frac")),
             ]) + " |")
         add("")
-    for rec, _ in checked:
-        if not rec.tuning:
-            continue
-        t = dict(rec.tuning)
-        router = dict(t.get("router") or {})
-        add(f"## Online tuning — {rec.engine} engine, budget "
-            f"{_fmt(t.get('budget'))}")
-        add("")
-        add(f"{_fmt(t.get('decisions'))} bandit decisions, total regret "
-            f"{_fmt(t.get('regret_us_total'))} µs vs the running best. "
-            "Arm 0 is the warm start (the committed `tuned.json` entry "
-            "when one matches the exact 5-tuple key, the static default "
-            "otherwise); `committed µs` is that entry's offline proxy "
-            "timing — a different clock than the observed interpret "
-            "walls, recorded for provenance, never compared. The "
-            "`online_ceiling` claim replays every event below.")
-        add("")
-        add("| key | arms | pulls | warm | committed µs | warm-obs µs | "
-            "best µs | winner arm | winner tiles |")
-        add("|---|---|---|---|---|---|---|---|---|")
-        for key, kd in sorted(dict(t.get("keys", {})).items()):
-            kd = dict(kd)
-            arms = [dict(a) for a in kd.get("arms", [])]
-            winner = kd.get("winner")
-            tiles = "—"
-            if winner is not None and 0 <= int(winner) < len(arms):
-                tiles = ", ".join(f"{k}={v}" for k, v in
-                                  sorted(arms[int(winner)].items())) \
-                    or "—"
-            add("| " + " | ".join([
-                f"`{key}`", str(len(arms)),
-                _fmt(len(kd.get("events", []))),
-                str(kd.get("warm_source", "—")),
-                _fmt(kd.get("committed_us")), _fmt(kd.get("warm_us")),
-                _fmt(kd.get("best_us")), _fmt(winner), tiles,
-            ]) + " |")
-        add("")
-        if router.get("decisions"):
-            add(f"### Router decisions (SLO {_fmt(router.get('slo_ms'))} "
-                f"ms, max width {_fmt(router.get('max_width'))}, band "
-                f"[{_fmt(router.get('shrink_depth'))}, "
-                f"{_fmt(router.get('grow_depth'))}])")
-            add("")
-            add("| clock s | engine | depth | headroom ms | width | "
-                "explore | reason |")
-            add("|---|---|---|---|---|---|---|")
-            for d in router["decisions"]:
-                d = dict(d)
-                add("| " + " | ".join([
-                    _fmt(d.get("clock_s")), str(d.get("engine")),
-                    _fmt(d.get("queue_depth")),
-                    _fmt(d.get("headroom_ms")), _fmt(d.get("width")),
-                    _fmt(bool(d.get("explore"))),
-                    str(d.get("reason")),
-                ]) + " |")
-            add("")
     for rec, _ in checked:
         if not rec.events:
             continue
@@ -1034,9 +834,8 @@ def render_kernel_page(rs: RecordSet) -> str:
     real_cols = ("| mesh wall µs | coll µs | skew " if real else "")
     add("| engine | size | dtype | ref µs (median) | IQR µs | iters | "
         "pred µs v5e | I (Eq. 2) | memory-bound | auto | MXU ceiling | "
-        f"Eq. 23/24 bound | max err | tile config | tuned Δ {shard_cols}"
-        f"{real_cols}| claims |")
-    add("|---|---|---|---|---|---|---|---|---|---|---|---|---|---|---|"
+        f"Eq. 23/24 bound | max err {shard_cols}{real_cols}| claims |")
+    add("|---|---|---|---|---|---|---|---|---|---|---|---|---|"
         + ("---|" * 4 if mesh > 1 else "")
         + ("---|" * 3 if real else "") + "---|")
     checked = _check_set(rs)
@@ -1050,8 +849,7 @@ def render_kernel_page(rs: RecordSet) -> str:
             _fmt(rec.intensity), _fmt(rec.memory_bound),
             rec.engine_auto, f"{_fmt(rec.mxu_ceiling)}x",
             f"{_fmt(ceiling_bound(rec.intensity, hw))}x",
-            _fmt(rec.max_err, 3), _tile_cell(rec),
-            _tuned_delta_cell(rec),
+            _fmt(rec.max_err, 3),
         ]
         if mesh > 1:
             spec = dict(rec.shard_spec or {})

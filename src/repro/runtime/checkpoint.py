@@ -15,8 +15,8 @@ against the new sharding -- this is the elastic re-shard path.
 Restore is also corruption-tolerant when no explicit step is pinned: a
 checkpoint that turns out unreadable on disk (truncated npz, mangled
 manifest) is skipped with a warning and the next older complete step is
-tried, mirroring the tuning cache's warn-and-fall-back policy — crash
-recovery should degrade to an older snapshot, not refuse to start.
+tried — crash recovery should degrade to an older snapshot, not refuse
+to start.
 Asking for a *specific* ``step=`` stays strict: the caller named the
 state they need, so silently serving older state would be a lie.
 """
@@ -114,8 +114,7 @@ def restore(ckpt_dir: str | Path, template: Pytree, step: Optional[int] = None,
 
     With ``step=None`` (resume-from-newest), a corrupt step on disk is
     skipped with a ``repro.obs.log`` warning record and the next older
-    complete step is tried — same warn-and-fall-back contract as the
-    tuning cache.  An explicit ``step`` is strict and raises on
+    complete step is tried.  An explicit ``step`` is strict and raises on
     corruption.
     """
     ckpt_dir = Path(ckpt_dir)

@@ -22,10 +22,7 @@ arrives as a request stream.  The layers:
   ``benchmarks/compare.py`` p99/goodput gate.
 * :mod:`repro.serving.session` — the one-call session driver.
 * :mod:`repro.serving.router` — the SLO-aware control plane: shard
-  width + exploration gating from queue depth and SLO headroom, and
-  the online-tuning batch executor whose tiles are re-tuned live by
-  the :mod:`repro.tuning.online` bandit (``serve --online-tune
-  [--slo-route]``).
+  width from queue depth and SLO headroom (``serve --slo-route``).
 * :mod:`repro.serving.elastic` — the elastic, fault-tolerant session:
   mesh resizes under load (``Dispatcher.set_mesh`` +
   ``runtime/elastic.mesh_transition_plan``), bit-exact re-dispatch of
@@ -49,7 +46,7 @@ from .lm import LMDecodeExecutor, decode_traits
 from .metrics import (ServingSummary, format_summary, percentile,
                       serving_record, summarize)
 from .requests import LM_DECODE, Request, RequestResult
-from .router import OnlineKernelBatchExecutor, RouterDecision, SLORouter
+from .router import RoutedKernelBatchExecutor, RouterDecision, SLORouter
 from .scheduler import (BatchExecution, BatchPolicy,
                         ContinuousBatchingScheduler, ServingLog)
 from .session import SessionConfig, run_session
@@ -60,9 +57,9 @@ __all__ = [
     "ChaosInjector", "ClosedLoopLoadGen", "ContinuousBatchingScheduler",
     "DEFAULT_SLO", "ElasticKernelExecutor", "ElasticSession",
     "KernelBatchExecutor", "LMDecodeExecutor", "LM_DECODE", "LoadGen",
-    "OnlineKernelBatchExecutor", "PoissonLoadGen", "Request",
-    "RequestResult", "RouterDecision", "SLO", "SLORouter", "ServingLog",
-    "ServingSummary", "SessionConfig", "TraceLoadGen", "WORKLOADS",
+    "PoissonLoadGen", "Request", "RequestResult",
+    "RoutedKernelBatchExecutor", "RouterDecision", "SLO", "SLORouter",
+    "ServingLog", "ServingSummary", "SessionConfig", "TraceLoadGen", "WORKLOADS",
     "checkpoint_session", "decode_traits", "format_summary", "load_trace",
     "make_loadgen", "percentile", "redispatch_failed_shard", "run_session",
     "save_trace", "serving_record", "summarize",

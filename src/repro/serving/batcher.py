@@ -6,8 +6,8 @@ kernel families: a formed batch of same-(kernel, dtype) requests is
 call arguments are scalars plus same-length 1-D arrays — SCALE, STREAM
 Triad, AXPY), by concatenating each array argument across requests and
 padding to a *fixed capacity* derived from the policy's ``max_batch``
-and the dispatch layer's tile shape (``block_rows × lanes``, tuned or
-static).  Fixed-capacity packing is what keeps the hot path hot: every
+and the elementwise wrapper's static tile (``block_rows × lanes``).
+Fixed-capacity packing is what keeps the hot path hot: every
 launch of a (kernel, dtype, engine) triple reuses one compiled shape,
 and engine selection is the dispatcher's memoized Advice (paper §6) —
 a dict hit, not a roofline re-derivation, exactly as the paper's
@@ -21,7 +21,7 @@ just not the launch itself.
 Under a mesh (``num_shards > 1``) the packed launch splits shard-wise
 via :mod:`repro.sharding`: the packed capacity rounds up to whole
 tiles *per shard*, each shard launches through the dispatcher (same
-memoized Advice, same tuned tiles), and the batch is charged the
+memoized Advice, same static tiles), and the batch is charged the
 **shard-parallel** compute time — the slowest shard, which is what an
 N-device mesh would fold into the virtual clock.  The per-request
 fallback shards each request the same way.
@@ -52,8 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.dispatch import (DEFAULT_DISPATCHER, ELEMENTWISE_BLOCK_ROWS,
-                             ELEMENTWISE_LANES)
+from ..core.dispatch import ELEMENTWISE_BLOCK_ROWS, ELEMENTWISE_LANES
 from ..kernels import registry
 from ..sharding import ShardedExecutor
 from ..sharding.executor import MeshExecutor
@@ -133,23 +132,16 @@ class KernelBatchExecutor:
                 return False
         return saw_array
 
-    def _capacity(self, kernel: str, engine: str, total: int,
-                  dtype: str) -> int:
+    def _capacity(self, total: int) -> int:
         """Packed length: max_batch × per-request size, tile-rounded.
 
-        Uses the tile shape dispatch would launch with (tuned
-        ``block_rows``/``lanes`` when cached, static defaults
-        otherwise) so padding always lands on a whole number of tiles.
-        Under a mesh the unit is ``num_shards`` tiles: the packed
-        array splits into equal per-shard ranges that each cover whole
-        tiles, so every shard reuses one compiled shape too.
+        Uses the elementwise wrapper's static tile so padding always
+        lands on a whole number of tiles.  Under a mesh the unit is
+        ``num_shards`` tiles: the packed array splits into equal
+        per-shard ranges that each cover whole tiles, so every shard
+        reuses one compiled shape too.
         """
-        params = DEFAULT_DISPATCHER.tuning.lookup(
-            kernel, engine, dtype, DEFAULT_DISPATCHER.hw.name,
-            num_shards=self.num_shards)
-        cfg = dict(params.params) if params is not None else {}
-        tile = (cfg.get("block_rows", ELEMENTWISE_BLOCK_ROWS)
-                * cfg.get("lanes", ELEMENTWISE_LANES)) * self.num_shards
+        tile = ELEMENTWISE_BLOCK_ROWS * ELEMENTWISE_LANES * self.num_shards
         cap = max(total, 1)
         return -(-cap // tile) * tile  # ceil to a whole tile count
 
@@ -178,17 +170,6 @@ class KernelBatchExecutor:
         return self._shard_exec.run(op, *args, engine=engine,
                                     plan=plan, **kwargs).parallel_s
 
-    def _tile_override(self, op, engine: str, dtype: str):
-        """Per-launch tile-config override hook (None = dispatch decides).
-
-        The base executor never overrides: tuned tiles come from the
-        dispatcher's TuningPolicy.  The online-tuning executor
-        (:class:`repro.serving.router.OnlineKernelBatchExecutor`)
-        overrides this to inject the bandit's current arm into
-        full-width packed launches.
-        """
-        return None
-
     def _resolve_engine(self, op, args, kwargs) -> Tuple[str, str]:
         """(engine to run, what 'auto' would pick) via memoized Advice."""
         auto = op.advice(*args, **kwargs).engine
@@ -214,9 +195,7 @@ class KernelBatchExecutor:
         # ours degrades to an extra compile instead of a crash
         total = sum(r.size for r in batch)
         cap = self._capacity(
-            op.name, engine,
-            max(self.max_batch * max(r.size for r in batch), total),
-            dtype)
+            max(self.max_batch * max(r.size for r in batch), total))
         packed = []
         template_args = per_req[0][0]
         for i, a in enumerate(template_args):
@@ -237,20 +216,15 @@ class KernelBatchExecutor:
             return self._sharded_compute(op, tuple(packed), {}, engine,
                                          plan_key=(op.name, dtype, cap),
                                          warm_key=warm_key)
-        tile = self._tile_override(op, engine, dtype)
-        if tile is not None:
-            warm_key = warm_key + (tuple(sorted(tile.items())),)
-        launch_kw = ({} if tile is None else {"tile_config": dict(tile)})
         if warm_key not in self._warmed:
             # first launch of this compiled shape: compile outside the
             # timed region so p99 measures serving, not tracing
             jax.block_until_ready(op(*packed, engine=engine,
-                                     interpret=self.interpret,
-                                     **launch_kw))
+                                     interpret=self.interpret))
             self._warmed.add(warm_key)
         t0 = time.perf_counter()
         jax.block_until_ready(op(*packed, engine=engine,
-                                 interpret=self.interpret, **launch_kw))
+                                 interpret=self.interpret))
         return time.perf_counter() - t0
 
     def _run_sequential(self, op, batch: Sequence[Request],

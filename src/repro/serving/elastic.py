@@ -25,8 +25,8 @@ giving up one bit of the paper's verdict.  Three integration points:
   failures cost latency, never answers.
 * **Checkpoint/restore** — :func:`checkpoint_session` snapshots the
   scheduler cursor (clock, batch id, completed request ids), the
-  engine cache (the canonical per-class inputs), the per-request
-  fingerprints, and the tuner state through
+  engine cache (the canonical per-class inputs) and the per-request
+  fingerprints through
   :class:`repro.runtime.checkpoint.AsyncCheckpointer`;
   :meth:`ElasticSession.restore` resumes the session from disk and
   serves only the not-yet-completed arrivals, landing on the same
@@ -187,7 +187,7 @@ def redispatch_failed_shard(op, plan: ShardPlan, failed_index: int,
     :class:`~repro.sharding.plan.ShardPlan` already names exactly which
     slice of the call the dead shard owned, so recovery is one plain
     dispatched launch of ``shard_call(plan, shards[failed_index], ...)``
-    — same §6 engine routing, same tuned tiles, same interpret-mode
+    — same §6 engine routing, same static tiles, same interpret-mode
     math as the original shard, hence bit-exact output.  Returns
     ``(output, recovery_seconds)``; the caller charges the seconds to
     the batch on the virtual clock and splices the output in place of
@@ -204,7 +204,7 @@ def redispatch_failed_shard(op, plan: ShardPlan, failed_index: int,
     sargs, skw = shard_call(plan, shard, args, kwargs)
     disp = dispatcher if dispatcher is not None else DEFAULT_DISPATCHER
     if disp.mesh_shards > 1:
-        disp = Dispatcher(advisor=disp.advisor, tuning=disp.tuning)
+        disp = Dispatcher(advisor=disp.advisor)
     t0 = time.perf_counter()
     out = disp.run(op, *sargs, engine=engine, interpret=interpret, **skw)
     jax.block_until_ready(out)
@@ -812,8 +812,8 @@ def checkpoint_session(session: ElasticSession, ckpt_dir, *,
     ids and their fingerprints (scheduler state — what must not be
     served twice), the session checksum, the canonical per-class input
     arrays (engine-cache state — verified bit-exact on restore), and in
-    the manifest's ``extra`` the virtual-clock cursor, mesh width,
-    event log, and the dispatcher's tuner entries.  Waits for the write
+    the manifest's ``extra`` the virtual-clock cursor, mesh width and
+    event log.  Waits for the write
     so a crash immediately after this call still finds a complete
     checkpoint; ``keep`` prunes older steps
     (:func:`repro.runtime.checkpoint.prune_old`).  Returns the step
@@ -843,12 +843,6 @@ def checkpoint_session(session: ElasticSession, ckpt_dir, *,
         "checksum": np.float64(session.checksum()),
         "inputs": inputs_tree,
     }
-    cache = session.dispatcher.tuning.cache
-    tuning_state = []
-    if cache is not None:
-        for entry in cache:
-            to_json = getattr(entry, "to_json", None)
-            tuning_state.append(to_json() if to_json else repr(entry))
     extra = {
         "n_completed": len(rids),
         "clock": state["clock"],
@@ -860,7 +854,6 @@ def checkpoint_session(session: ElasticSession, ckpt_dir, *,
         "classes": classes,
         "kernel": session.cfg.kernel,
         "seed": session.cfg.seed,
-        "tuning": tuning_state,
     }
     step = int(state["batch_id"]) if step is None else int(step)
     writer = session._checkpointer(ckpt_dir)

@@ -133,7 +133,6 @@ def serving_record(summary: ServingSummary, *, kernel: str, engine: str,
                    phases: Optional[Dict] = None,
                    verdict: Optional[Dict] = None,
                    events: Optional[Dict] = None,
-                   tuning: Optional[Dict] = None,
                    trace: Optional[Dict] = None,
                    results: Optional[Sequence[RequestResult]] = None,
                    ) -> Dict:
@@ -166,13 +165,6 @@ def serving_record(summary: ServingSummary, *, kernel: str, engine: str,
     sessions, and then absent from the record (event-less records keep
     the pre-elastic claim set).
 
-    Online-tuned sessions
-    (:class:`~repro.serving.router.OnlineKernelBatchExecutor`) carry
-    ``tuning``: the bandit's per-key arms and event log
-    (``tuning_events``) plus the router's decision history, which the
-    ``online_ceiling`` claim replays decision-by-decision.  None for
-    statically-tuned sessions, and then absent from the record.
-
     ``trace`` is the observability reconciliation block (see
     :func:`repro.serving.scheduler.trace_payload`): the tracer's
     independent account of the virtual timeline, checked against this
@@ -184,7 +176,6 @@ def serving_record(summary: ServingSummary, *, kernel: str, engine: str,
         **({"phases": dict(phases)} if phases is not None else {}),
         **({"verdict": dict(verdict)} if verdict is not None else {}),
         **({"events": dict(events)} if events is not None else {}),
-        **({"tuning": dict(tuning)} if tuning is not None else {}),
         **({"trace": dict(trace)} if trace is not None else {}),
         "num_shards": int(num_shards),
         "mesh_exec_mode": (str(mesh_exec_mode)

@@ -220,9 +220,9 @@ class ContinuousBatchingScheduler:
             q = queues[key]
             batch = [q.popleft()
                      for _ in range(min(self.policy.max_batch, len(q)))]
-            # executors that adapt to load (the SLO router / online
-            # tuner) observe the dequeue signals here, before the
-            # launch; plain executors simply lack the hook
+            # executors that adapt to load (the SLO router) observe
+            # the dequeue signals here, before the launch; plain
+            # executors simply lack the hook
             notify = getattr(self.executor, "on_dequeue", None)
             if notify is not None:
                 depth = len(batch) + sum(len(qq)

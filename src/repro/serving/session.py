@@ -48,15 +48,9 @@ class SessionConfig:
     # execute sharded launches on real devices (MeshExecutor, measured
     # wall time) instead of the virtual clock's modeled max-over-shards
     real_mesh: bool = False
-    # online tile tuning: a budgeted bandit re-tunes from measured
-    # batch compute times (repro.tuning.online), warm-started from the
-    # committed tuned.json; the record gains a `tuning` block
-    online_tune: bool = False
-    # SLO-aware routing: shard width + exploration gating from queue
-    # depth and SLO headroom (repro.serving.router.SLORouter);
-    # requires online_tune
+    # SLO-aware routing: shard width from queue depth and SLO
+    # headroom (repro.serving.router.SLORouter), starting at width 1
     slo_route: bool = False
-    tune_budget: int = 8         # bandit exploration pulls per key
 
 
 def run_session(cfg: SessionConfig, executor=None,
@@ -72,26 +66,16 @@ def run_session(cfg: SessionConfig, executor=None,
     memoized Advice (Eq. 2 intensity, Eq. 4 boundedness, the
     Eq. 17/23/24 ceiling, §6 auto-routing) onto the summary.
     """
-    if cfg.slo_route and not cfg.online_tune:
-        raise ValueError("slo_route requires online_tune: the router's "
-                         "exploration gate drives the online tuner")
     restore_mesh = None
-    if executor is None and cfg.online_tune:
+    if executor is None and cfg.slo_route:
         if cfg.num_shards != 1 or cfg.real_mesh:
             raise ValueError(
-                "online_tune owns the mesh width (the router grows and "
+                "slo_route owns the mesh width (the router grows and "
                 "shrinks it); start from num_shards=1, virtual clock")
-        from ..core.dispatch import DEFAULT_DISPATCHER
-        from ..tuning.online import OnlineTuner
-        from .router import OnlineKernelBatchExecutor, SLORouter
-        tuner = OnlineTuner(cfg.tune_budget,
-                            cache=DEFAULT_DISPATCHER.tuning.cache,
-                            hw_model=DEFAULT_DISPATCHER.hw.name)
-        router = SLORouter(slo_ms=cfg.slo.latency_ms) if cfg.slo_route \
-            else None
-        executor = OnlineKernelBatchExecutor(
+        from .router import RoutedKernelBatchExecutor, SLORouter
+        executor = RoutedKernelBatchExecutor(
             engine=cfg.engine, max_batch=cfg.policy.max_batch,
-            seed=cfg.seed, tuner=tuner, router=router)
+            seed=cfg.seed, router=SLORouter(slo_ms=cfg.slo.latency_ms))
         # the router mutates the global dispatcher's mesh width; put
         # it back so later sessions start from the configured state
         restore_mesh = executor.dispatcher
@@ -142,6 +126,5 @@ def run_session(cfg: SessionConfig, executor=None,
         mesh_exec_mode=(("mesh" if cfg.real_mesh else "virtual")
                         if cfg.num_shards > 1 else None),
         model=extras.get("model"), phases=extras.get("phases"),
-        verdict=extras.get("verdict"), tuning=extras.get("tuning"),
-        trace=trace)
+        verdict=extras.get("verdict"), trace=trace)
     return log, summary, record

@@ -5,9 +5,8 @@ The runtime half of :mod:`repro.sharding.plan`: a
 (:func:`~repro.sharding.plan.plan_for`), and launches each shard
 through ``repro.core.dispatch.DEFAULT_DISPATCHER`` under a
 ``jax.make_mesh`` data axis — so every per-shard launch gets the §6
-engine decision and the per-(kernel, engine, dtype, hw) tuned tile
-config from the existing tuning cache, exactly as an unsharded call
-would.  Outputs are reassembled with
+engine decision and its family's static tile, exactly as an unsharded
+call would.  Outputs are reassembled with
 :func:`~repro.sharding.plan.combine_outputs` and must equal the
 unsharded result bit-for-bit (halo rows carry the trapezoid dependency
 of Eq. 13; data/head splits are independent).
@@ -68,7 +67,7 @@ class ShardedExecutor:
     The execution engine behind ``benchmarks.run sweep --mesh N`` and
     the serving batcher's shard-parallel packing: plans once per call
     shape, launches every shard through the dispatcher (memoized §6
-    Advice + tuned tiles per shard), and reassembles the exact
+    Advice + static tiles per shard), and reassembles the exact
     unsharded result.  ``engine``/``interpret`` follow the dispatch
     layer's conventions; ``num_shards=1`` degrades to a plain
     dispatched call wrapped in the same timing envelope.
@@ -93,14 +92,13 @@ class ShardedExecutor:
         mesh-configured dispatcher would plan a bogus sub-split onto
         its memoized Advice.  When the backing dispatcher has a mesh
         set, shards run through a flat (mesh-1) view sharing its
-        advisor and tuning policy, so §6 routing and tuned tiles are
-        identical and only the shard-spec planning is skipped.
+        advisor, so §6 routing is identical and only the shard-spec
+        planning is skipped.
         """
         if self.dispatcher.mesh_shards == 1:
             return self.dispatcher
         if self._flat is None:
-            self._flat = Dispatcher(advisor=self.dispatcher.advisor,
-                                    tuning=self.dispatcher.tuning)
+            self._flat = Dispatcher(advisor=self.dispatcher.advisor)
         return self._flat
 
     def mesh(self):
@@ -118,7 +116,7 @@ class ShardedExecutor:
         """Plan, launch every shard via dispatch, and reassemble.
 
         Each shard's launch is a normal ``Dispatcher.run`` — §6 engine
-        routing and tuned tile lookup included — timed individually so
+        routing included — timed individually so
         callers can account the shard-parallel (max) or serial (sum)
         cost.  Pass *plan* to reuse a prior plan across calls of the
         same shape (the serving batcher's steady-state path).

@@ -2,7 +2,7 @@
 actionlint-equivalent gate runnable in this container): both workflows
 must parse as YAML and carry the shapes CI correctness depends on —
 the split lint+unit/slow matrix with cancel-in-progress on PRs, and
-the scheduled nightly sweep + tune job with artifact upload."""
+the scheduled nightly sweep job with artifact upload."""
 import pathlib
 
 import pytest
@@ -47,8 +47,7 @@ def test_ci_matrix_split():
     wf = _load("ci.yml")
     jobs = wf["jobs"]
     assert set(jobs) == {"lint-unit", "mesh-smoke", "lm-smoke",
-                         "chaos-smoke", "trace-smoke", "online-smoke",
-                         "slow"}
+                         "chaos-smoke", "trace-smoke", "slow"}
 
     lint = jobs["lint-unit"]
     matrix = lint["strategy"]["matrix"]["python-version"]
@@ -80,9 +79,11 @@ def test_ci_cancels_superseded_pr_runs():
     assert "pull_request" in str(conc["cancel-in-progress"])
 
 
-def test_ci_pr_gate_uses_tuned_cache():
+def test_ci_pr_gate_sweeps_static_tiles():
     runs = _run_text(_load("ci.yml")["jobs"]["lint-unit"])
-    assert "--tuned tuned.json" in runs
+    # the tile is each kernel's own decision: no sweep takes a tile cache
+    assert "benchmarks.run scale axpy --out runs-ci" in runs
+    assert "--tuned" not in runs
     assert "benchmarks.compare runs runs-ci" in runs
     # the bench gate must not demand serving coverage of a bench-only
     # candidate sweep (and vice versa)
@@ -114,7 +115,7 @@ def test_ci_mesh_smoke_job():
     job = _load("ci.yml")["jobs"]["mesh-smoke"]
     runs = _run_text(job)
     assert "benchmarks.run scale stencil --mesh 2" in runs
-    assert "--tuned tuned.json" in runs
+    assert "--tuned" not in runs
     assert "benchmarks.compare runs runs-ci-mesh" in runs
     assert "--kind bench" in runs and "--mesh 2" in runs
     uploads = [s for s in job["steps"]
@@ -219,37 +220,10 @@ def test_pytest_tier_markers_declared():
         assert f'"{tier}: ' in text, f"marker {tier!r} not declared"
 
 
-def test_ci_online_smoke_job():
-    """The online-tuning smoke: serve --online-tune --slo-route on the
-    two cheapest families, warm-started from the committed tuned.json,
-    gated (incl. the online_ceiling claim replay and the regret gate)
-    against the committed online baseline.  Bare traffic knobs are
-    load-bearing: tune_budget is a comparability knob, so compare.py
-    refuses a drifted exploration budget."""
-    job = _load("ci.yml")["jobs"]["online-smoke"]
-    runs = _run_text(job)
-    assert "benchmarks.run serve --online-tune --slo-route" in runs
-    assert "--kernels scale,axpy" in runs
-    assert "--tuned tuned.json" in runs
-    assert "--out runs-ci-online" in runs
-    assert "benchmarks.compare runs runs-ci-online" in runs
-    assert "--kind serving --mesh 1" in runs
-    # no traffic/budget knobs on the serve command (defaults must
-    # match the committed online baseline exactly)
-    serve_line = next(line for line in runs.splitlines()
-                      if "benchmarks.run serve" in line)
-    for knob in ("--rate", "--duration", "--max-batch", "--slo-ms",
-                 "--seed", "--size", "--tune-budget"):
-        assert knob not in serve_line
-    uploads = [s for s in job["steps"]
-               if "upload-artifact" in s.get("uses", "")]
-    assert uploads and "runs-ci-online" in uploads[0]["with"]["path"]
-
-
 def test_nightly_covers_committed_mesh_widths():
     """The nightly bench gate runs with the default --mesh all, so its
     candidate sweep must reproduce every committed mesh width."""
-    runs = _run_text(_load("nightly.yml")["jobs"]["sweep-and-tune"])
+    runs = _run_text(_load("nightly.yml")["jobs"]["sweep"])
     assert "benchmarks.run scale stencil --mesh 2" in runs
     assert "benchmarks.run scale --mesh 4" in runs
 
@@ -261,15 +235,15 @@ def test_nightly_schedule_and_artifacts():
     assert crons and all(len(c.split()) == 5 for c in crons)
     assert "workflow_dispatch" in on
 
-    job = wf["jobs"]["sweep-and-tune"]
+    job = wf["jobs"]["sweep"]
     runs = _run_text(job)
-    # full sweep + regression gate + serving sweep + tune smoke
-    assert "benchmarks.run kernels --tuned tuned.json" in runs
+    # full sweep + regression gate + serving sweep
+    assert "benchmarks.run kernels --out runs-nightly" in runs
     assert "benchmarks.compare runs runs-nightly" in runs
-    assert "benchmarks.run serve --tuned tuned.json" in runs
+    assert "benchmarks.run serve --out runs-serve-nightly" in runs
     assert "benchmarks.compare runs runs-serve-nightly" in runs
     assert "--kind serving --mesh 1" in runs
-    assert "benchmarks.run tune --budget" in runs
+    assert "--tuned" not in runs
     # the chaos sweep replays the committed adversary and gates it
     assert "--chaos \"fail@0.6:1,resize@1.1:4,resize@1.6:2\"" in runs
     assert "benchmarks.compare runs runs-chaos-nightly" in runs
@@ -278,6 +252,6 @@ def test_nightly_schedule_and_artifacts():
                if "upload-artifact" in s.get("uses", "")]
     assert uploads and uploads[0].get("if") == "always()"
     path = uploads[0]["with"]["path"]
-    assert "tuned-nightly.json" in path and "compare-gate.txt" in path
+    assert "compare-gate.txt" in path
     assert "runs-serve-nightly" in path and "serve-gate.txt" in path
     assert "runs-chaos-nightly" in path and "chaos-gate.txt" in path

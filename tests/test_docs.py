@@ -1,22 +1,11 @@
-"""Documentation integrity gates.
+"""Documentation integrity gates (a CI step, see .github/workflows/ci.yml).
 
-Two families of checks, both CI steps (see .github/workflows/ci.yml):
-
-* **link checking** — every relative ``.md``/file link in the docs
-  tree (plus README/REPORT) must resolve against the repo, and every
-  backtick ``path:line`` reference in docs/ must point at a real file
-  that is long enough.  Docs that point nowhere rot silently; this
-  makes a broken pointer a red build instead.
-* **schema agreement** — the tuned.json field names documented in
-  docs/tuning.md, the ``--tuned`` help text in ``benchmarks/run.py``,
-  and the dataclasses/record builders that define them
-  (``repro.tuning.cache.TunedEntry``,
-  ``benchmarks.bench_kernels._tile_config_field``) must all agree —
-  the regression test for the drift where the docs described one set
-  of field names and the code wrote another.
+Link checking: every relative ``.md``/file link in the docs tree (plus
+README/REPORT) must resolve against the repo, and every backtick
+``path:line`` reference in docs/ must point at a real file that is long
+enough.  Docs that point nowhere rot silently; this makes a broken
+pointer a red build instead.
 """
-import dataclasses
-import json
 import pathlib
 import re
 
@@ -68,72 +57,3 @@ def test_file_line_references_resolve(page):
             bad.append(f"{path}:{line} (file is shorter)")
     assert not bad, (
         f"{page.relative_to(REPO)}: stale file:line reference(s) {bad}")
-
-
-# --------------------------------------------------------------------------
-# tuned.json schema: docs, CLI help, and code must agree
-# --------------------------------------------------------------------------
-
-def _tuning_md_example():
-    """The fenced JSON example from docs/tuning.md's cache-schema section."""
-    text = (DOCS / "tuning.md").read_text()
-    section = text.split("## Cache schema", 1)[1]
-    block = section.split("```json", 1)[1].split("```", 1)[0]
-    return json.loads(block)
-
-
-def test_tuned_schema_field_names_agree():
-    """docs/tuning.md's example entry must parse as a real TunedEntry."""
-    from repro.tuning.cache import CACHE_SCHEMA, TunedEntry
-
-    payload = _tuning_md_example()
-    assert payload["schema"] == CACHE_SCHEMA
-    field_names = {f.name for f in dataclasses.fields(TunedEntry)}
-    for raw in payload["entries"]:
-        unknown = set(raw) - field_names
-        assert not unknown, (
-            f"docs/tuning.md documents field(s) {sorted(unknown)} that "
-            f"TunedEntry does not define (has {sorted(field_names)})")
-        entry = TunedEntry.from_json(raw)  # must not raise
-        assert entry.params
-
-
-def test_record_tile_config_field_names_agree():
-    """The cache->record rename (best_us -> tuned_us) is documented
-    everywhere it is consumed: the docs table, run.py's --tuned help,
-    and the record builder itself write the same names."""
-    from benchmarks import run as run_mod
-    from benchmarks.bench_kernels import _tile_config_field
-    from repro.core.dispatch import Dispatcher
-    from repro.kernels import registry
-    from repro.tuning.cache import TunedEntry, TuningCache
-
-    # what the record builder actually writes, from a synthetic cache
-    dispatcher = Dispatcher()
-    dispatcher.set_tuning_cache(TuningCache([TunedEntry(
-        kernel="scale", engine="vector", dtype="float32",
-        hw_model=dispatcher.hw.name,
-        params={"block_rows": 128, "lanes": 512},
-        best_us=10.0, default_us=15.0, size=4096)]))
-    import benchmarks.bench_kernels as bk
-    orig = bk.DEFAULT_DISPATCHER
-    bk.DEFAULT_DISPATCHER = dispatcher
-    try:
-        field = _tile_config_field(registry.get("scale"), "vector",
-                                   "float32")
-    finally:
-        bk.DEFAULT_DISPATCHER = orig
-    assert field is not None
-    record_keys = set(field)
-    assert record_keys == {"params", "tuned_us", "default_us", "source"}
-
-    tuning_md = (DOCS / "tuning.md").read_text()
-    run_doc = run_mod.__doc__
-    for name in sorted(record_keys - {"params", "source"}):
-        assert name in tuning_md, (
-            f"docs/tuning.md never mentions record field {name!r}")
-        assert name in run_doc, (
-            f"benchmarks/run.py --tuned help never mentions record "
-            f"field {name!r}")
-    # the cache-side name the rename maps from is documented on both
-    assert "best_us" in tuning_md and "best_us" in run_doc

@@ -29,7 +29,6 @@ MODULES = (
     "repro.serving.slo",
     "repro.serving.elastic",
     "repro.serving.router",
-    "repro.tuning.online",
     "repro.runtime.checkpoint",
     "repro.runtime.elastic",
     "repro.sharding",
@@ -65,7 +64,6 @@ PAPER_CITED = (
     ("repro.serving.metrics", "serving_record"),
     ("repro.serving.session", "run_session"),
     ("repro.serving.router", "SLORouter"),
-    ("repro.tuning.online", "OnlineTuner"),
     ("repro.sharding.plan", "ShardSpec"),
     ("repro.sharding.plan", "ShardPlan"),
     ("repro.sharding.plan", "plan_for"),

@@ -237,7 +237,8 @@ def test_scheduler_state_survives_restart(tmp_path):
     step = checkpoint_session(interrupted, tmp_path)
     assert ckpt.latest_step(tmp_path) == step
     extra = ckpt.checkpoint_meta(tmp_path, step)["extra"]
-    assert extra["tuning"] is not None  # tuner cache rode along
+    assert extra["kernel"] == cfg.kernel
+    assert extra["n_completed"] == len(interrupted._state["fingerprints"])
 
     resumed = ElasticSession.restore(cfg, tmp_path)
     done_before = set(resumed._resume["completed"])

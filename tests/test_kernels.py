@@ -21,6 +21,8 @@ def _hypothesis_stub():
     silently-uncollected coverage."""
     pytest.skip("hypothesis not installed (pip install -e .[dev])")
 
+from repro.kernels import registry
+from repro.kernels.axpy.ref import axpy_ref
 from repro.kernels.scale.ops import scale
 from repro.kernels.scale.ref import scale_ref
 from repro.kernels.spmv.ops import dense_to_bell, spmv
@@ -28,13 +30,28 @@ from repro.kernels.spmv.ref import bell_matvec_ref, csr_spmv_ref
 from repro.kernels.stencil.defs import suite
 from repro.kernels.stencil.ops import stencil
 from repro.kernels.stencil.ref import stencil_ref
+from repro.kernels.triad.ref import triad_ref
 
 ENGINES = ["vpu", "mxu"]
 
 
 # --------------------------------------------------------------------------
-# SCALE
+# Elementwise (SCALE, STREAM Triad, AXPY)
 # --------------------------------------------------------------------------
+
+def _elementwise_args(name, shape, dtype):
+    """Call arguments of one elementwise family (scalars exact in bf16)."""
+    rng = np.random.default_rng(0)
+
+    def arr():
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    if name == "scale":
+        return (arr(), 2.5)
+    if name == "triad":
+        return (arr(), arr(), 1.5)
+    return (0.75, arr(), arr())  # axpy
+
 
 @pytest.mark.parametrize("engine", ENGINES + ["auto"])
 @pytest.mark.parametrize("shape", [(17,), (1024,), (300_000,), (33, 95)])
@@ -49,6 +66,40 @@ def test_scale_matches_ref(engine, shape, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=2e-2 if dtype == jnp.bfloat16 else 1e-6)
+
+
+@pytest.mark.parametrize("name,ref", [("triad", triad_ref),
+                                      ("axpy", axpy_ref)])
+@pytest.mark.parametrize("engine", ENGINES + ["auto"])
+@pytest.mark.parametrize("shape", [(17,), (1024,), (300_000,), (33, 95)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_elementwise_matches_ref(name, ref, engine, shape, dtype):
+    """The two-operand families at SCALE's engines, shapes and dtypes."""
+    args = _elementwise_args(name, shape, dtype)
+    got = registry.get(name)(*args, engine=engine)
+    want = ref(*args)
+    assert got.shape == shape and got.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
+    # a sum of two unit-scale operands can cancel, so its rounding error
+    # is bounded against the operands, not the result
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("name,ref", [("scale", scale_ref),
+                                      ("triad", triad_ref),
+                                      ("axpy", axpy_ref)])
+@pytest.mark.parametrize("engine", ENGINES)
+def test_elementwise_explicit_tile_matches_ref(name, ref, engine):
+    """A caller's tile kwargs reach the body as given: an 8x128 tile
+    over a (33, 95) array pads to several grid steps, and the traits
+    factory ignores the kwargs."""
+    args = _elementwise_args(name, (33, 95), jnp.float32)
+    got = registry.get(name)(*args, engine=engine, block_rows=8, lanes=128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref(*args)),
+                               rtol=1e-6, atol=0.0 if name == "scale"
+                               else 1e-6)
 
 
 if HAVE_HYPOTHESIS:

@@ -63,7 +63,7 @@ def test_load_committed_runs_schema6():
         for rec in s.records:
             assert rec.iters and rec.iqr_us is not None
             if rec.tile_config is not None:
-                assert rec.tile_params  # params map present + non-empty
+                assert rec.tile_config["params"]  # present + non-empty
                 tuned_points += 1
             # mesh sweeps carry a shard spec on every record; the
             # single-device baseline carries none
@@ -91,9 +91,8 @@ def test_load_schema3_tile_config(tmp_path):
     rs = load_file(str(p))
     assert rs.schema == 3
     tuned, untuned = rs.records
-    assert tuned.tile_params == {"block_rows": 128, "lanes": 512}
-    assert tuned.tuned_speedup == pytest.approx(1.5)
-    assert untuned.tile_config is None and untuned.tuned_speedup is None
+    assert tuned.tile_config == cfg  # kept as data
+    assert untuned.tile_config is None
     # malformed tile_config is rejected, not silently dropped
     payload["records"] = [_raw(tile_config={"tuned_us": 1.0})]
     p.write_text(json.dumps(payload))
@@ -225,26 +224,6 @@ def test_committed_report_is_current():
         render = (render_serving_page if rs.kind == "serving"
                   else render_kernel_page)
         assert page.read_text() == render(rs), page
-
-
-def test_report_renders_tuned_deltas(tmp_path):
-    """Kernel pages and REPORT.md show tuned-vs-default tile evidence."""
-    runs = tmp_path / "runs"
-    runs.mkdir()
-    cfg = {"params": {"block_rows": 128, "lanes": 512},
-           "tuned_us": 10.0, "default_us": 15.0, "source": "xla-proxy"}
-    payload = {"schema": 3, "kernel": "scale", "env": {},
-               "records": [_raw(tile_config=cfg), _raw(engine="matrix")]}
-    (runs / "BENCH_scale.json").write_text(json.dumps(payload))
-    recsets = load_dir(str(runs))
-    report = render_report(recsets)
-    assert "## Tuned tile configurations" in report
-    assert "block_rows=128, lanes=512" in report and "+50.0%" in report
-    page = render_kernel_page(recsets[0])
-    assert "tile config" in page and "tuned Δ" in page
-    assert "block_rows=128, lanes=512" in page and "+50.0%" in page
-    # the untuned record renders em-dashes, not empty cells
-    assert "| — | — |" in page
 
 
 def test_report_flags_violations(tmp_path):

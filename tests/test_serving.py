@@ -293,10 +293,9 @@ def test_schema4_round_trip(tmp_path):
     assert rs.kernel == "scale" and len(rs.records) == 2
     rec = rs.records[0]
     assert isinstance(rec, ServingRecord)
-    # legacy records (no num_shards, no tuning block) key as unsharded
-    # statically-tuned sessions
+    # legacy records (no num_shards) key as unsharded sessions
     assert rec.point == ("scale", "vector", "poisson", 65536,
-                         "float32", 1, "static")
+                         "float32", 1)
     assert rec.p99_ms == 25.0 and rec.memory_bound is True
     # the round-tripped record passes every serving claim
     results = check_serving_record(rec)
@@ -375,9 +374,6 @@ def test_committed_serving_runs_verify():
     assert serving, "no committed serving record sets under runs/"
     assert violations(check_records(serving)) == []
     for s in serving:
-        if any(r.tuning for r in s.records):
-            continue  # online sets carry one auto-routed session;
-            # their static vector/matrix pair lives in the base set
         engines = {r.engine for r in s.records}
         assert {"vector", "matrix"} <= engines  # both sides measured
 
@@ -529,78 +525,51 @@ def test_batcher_survives_oversized_policy_batches():
     assert result.engine == "vector" and result.compute_s > 0
 
 
-# -- online tuning + SLO routing --------------------------------------------
+# -- SLO routing ------------------------------------------------------------
 
-def test_online_replay_is_deterministic():
-    """Same seed ⟹ byte-identical ``tuning`` blocks (bandit events,
-    per-key stats, and router decisions).  Batch costs are a pure
-    function of the chosen arm, so the two sessions can only differ if
-    the policy itself smuggled in nondeterminism."""
-    from repro.serving import OnlineKernelBatchExecutor, SLORouter
-    from repro.tuning.online import OnlineTuner
+def test_slo_routed_session_is_deterministic():
+    """``slo_route``: the router sets the shard width at every dequeue
+    from queue depth and SLO headroom.  With batch costs a pure function
+    of the width, the same seed gives the same decisions, an overload
+    grows the width within [1, max_width], and the dispatcher's mesh is
+    put back after the session."""
+    from repro.core.dispatch import DEFAULT_DISPATCHER
+    from repro.serving import RoutedKernelBatchExecutor, SLORouter
 
-    class _DeterministicOnline(OnlineKernelBatchExecutor):
+    class _Deterministic(RoutedKernelBatchExecutor):
         def _run_packed(self, op, batch, engine):
-            tile = self._tile_override(op, engine, batch[0].dtype)
-            rows = (tile or {}).get("block_rows", 128)
-            return 2e-3 + rows * 1e-6  # pure function of the arm
+            return 1e-2 / self.num_shards  # pure function of the width
 
     def _session():
-        ex = _DeterministicOnline(
-            engine="auto", max_batch=4, seed=0,
-            tuner=OnlineTuner(4, hw_model="TPU-v5e"),
-            router=SLORouter(slo_ms=50.0, max_width=4))
+        ex = _Deterministic(engine="auto", max_batch=4, seed=0,
+                            router=SLORouter(slo_ms=50.0, max_width=4))
         cfg = SessionConfig(
-            kernel="scale", workload="poisson", rate_rps=400,
-            duration_s=0.5, size=4096, seed=0, online_tune=True,
-            slo_route=True, tune_budget=4,
+            kernel="scale", workload="poisson", rate_rps=2000,
+            duration_s=0.5, size=4096, seed=0, slo_route=True,
             policy=BatchPolicy(max_batch=4, max_wait_s=0.01))
         try:
-            _, _, rec = run_session(cfg, executor=ex)
+            _, summary, rec = run_session(cfg, executor=ex)
         finally:
             ex.dispatcher.set_mesh(1)
-        return rec
+        return ex.router.decisions, summary, rec
 
-    rec1, rec2 = _session(), _session()
-    assert json.dumps(rec1["tuning"], sort_keys=True) == \
-        json.dumps(rec2["tuning"], sort_keys=True)
-    t = rec1["tuning"]
-    assert t["mode"] == "online" and t["decisions"] > 0 and t["keys"]
-    assert t["router"]["decisions"]
+    (d1, s1, rec1), (d2, _, rec2) = _session(), _session()
+    assert d1 == d2 and rec1["p99_ms"] == rec2["p99_ms"]
+    assert "grow" in {d.reason for d in d1}
+    assert all(1 <= d.width <= 4 for d in d1)
+    assert all(d.engine == "vector" for d in d1)  # §6: memory-bound
+    assert s1.completed > 0 and rec1["engine"] == "vector"
+    assert DEFAULT_DISPATCHER.mesh_shards == 1
 
 
-def test_committed_online_baseline_verifies():
-    """The committed online-tuned serving baseline holds the PR's
-    acceptance bar: the ``online_ceiling`` claim passes with zero
-    violations, the adaptive session's p99 never regresses past the
-    static-tuned vector baseline, and every bandit arm sequence
-    replays byte-identically from the recorded events."""
-    from repro.tuning.online import replay
-
-    for kernel in ("scale", "axpy"):
-        online = load_file(str(RUNS / f"BENCH_serve_{kernel}_online.json"))
-        static = load_file(str(RUNS / f"BENCH_serve_{kernel}.json"))
-        recs = [r for r in online.records if r.tuning]
-        assert len(recs) == 1, f"{kernel}: expected one online session"
-        rec = recs[0]
-        results = check_serving_record(rec)
-        online_results = [r for r in results
-                          if r.claim == "online_ceiling"]
-        assert online_results and all(r.passed for r in online_results)
-        assert violations(results) == []
-        # acceptance: final p99 <= the static-tuned baseline's p99 on
-        # the engine §6 actually routes to (the vector leg)
-        vec = [r for r in static.records
-               if r.engine == "vector" and not r.tuning]
-        assert vec and rec.p99_ms <= vec[0].p99_ms
-        # acceptance: bandit decisions replay byte-identically
-        t = rec.tuning
-        for kd in t["keys"].values():
-            events = kd["events"]
-            assert events, "committed online key with no observations"
-            assert replay(len(kd["arms"]), t["budget"], events,
-                          bonus=t.get("bonus", 1.0)) \
-                == [e["arm"] for e in events]
+def test_slo_route_refuses_a_preset_mesh():
+    """The router owns the width: a session that also fixes a mesh, or
+    asks for the measured mesh, is refused before it serves."""
+    for kw in ({"num_shards": 2}, {"num_shards": 2, "real_mesh": True}):
+        cfg = SessionConfig(kernel="scale", duration_s=0.1, size=4096,
+                            slo_route=True, **kw)
+        with pytest.raises(ValueError, match="slo_route owns the mesh"):
+            run_session(cfg)
 
 
 # -- end-to-end (real kernel, small) ----------------------------------------

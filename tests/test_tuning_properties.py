@@ -1,16 +1,8 @@
-"""Property-based tier (hypothesis) for the tuning + routing layer.
+"""Property-based tier (hypothesis) for the serving control plane.
 
-Four algebraic contracts the online-tuning PR leans on, checked over
-generated inputs instead of hand-picked cases:
+Two contracts checked over generated inputs instead of hand-picked
+cases:
 
-* the tuning cache's **faster-wins merge** is commutative (per-key
-  winners agree whichever side merges first) and idempotent (merging a
-  cache into itself changes nothing) — the property that makes
-  repeated partial tuning runs accumulate instead of clobber,
-* the deterministic UCB bandit's **best-found cost is monotone
-  non-increasing in budget**: more exploration can only find better
-  (or equal) tiles, never worse — the property the compare gate's
-  regret arm assumes,
 * the **SLO router never overrides Eq. 23/24**: for memory-bound
   advice the decided engine is the vector engine at every queue
   depth/headroom, and the width trajectory stays inside
@@ -29,87 +21,11 @@ hypothesis = pytest.importorskip("hypothesis")
 
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core.dispatch import DEFAULT_DISPATCHER  # noqa: E402
 from repro.serving.batcher import KernelBatchExecutor  # noqa: E402
 from repro.serving.metrics import percentile  # noqa: E402
 from repro.serving.router import SLORouter  # noqa: E402
-from repro.tuning.cache import TunedEntry, TuningCache  # noqa: E402
-from repro.tuning.online import select_index  # noqa: E402
 
 pytestmark = pytest.mark.property
-
-HW = DEFAULT_DISPATCHER.hw.name
-
-# small but collision-rich key space: merges must be exercised on
-# overlapping keys, not just disjoint unions
-_entries = st.lists(
-    st.builds(
-        TunedEntry,
-        kernel=st.sampled_from(["scale", "triad"]),
-        engine=st.sampled_from(["vector", "matrix"]),
-        dtype=st.just("float32"),
-        hw_model=st.just(HW),
-        params=st.fixed_dictionaries(
-            {"block_rows": st.sampled_from([64, 128, 256])}),
-        best_us=st.floats(min_value=0.1, max_value=1e4,
-                          allow_nan=False, allow_infinity=False),
-        default_us=st.just(100.0),
-        size=st.just(4096),
-        shard_shape=st.sampled_from(["full", "2-way"]),
-    ),
-    max_size=8)
-
-
-def _winners(cache):
-    """The per-key best_us map (tie-safe merge fingerprint)."""
-    return {e.key: e.best_us for e in cache}
-
-
-@settings(max_examples=50, deadline=None)
-@given(_entries, _entries)
-def test_merge_commutative(a_entries, b_entries):
-    """Per-key winners agree whichever side the merge starts from."""
-    ab = TuningCache(a_entries).merge(TuningCache(b_entries))
-    ba = TuningCache(b_entries).merge(TuningCache(a_entries))
-    assert _winners(ab) == _winners(ba)
-
-
-@settings(max_examples=50, deadline=None)
-@given(_entries)
-def test_merge_idempotent(entries):
-    """Merging a cache into itself (or twice) changes nothing."""
-    once = TuningCache(entries).merge(TuningCache(entries))
-    twice = once.merge(TuningCache(entries))
-    assert {e.key: e for e in once} == {e.key: e for e in twice}
-
-
-def _best_found(costs, budget, steps):
-    """Drive the pure bandit policy on deterministic arm costs and
-    return the cheapest cost it discovered."""
-    pulls = [0] * len(costs)
-    sums = [0.0] * len(costs)
-    total = 0
-    for _ in range(steps):
-        means = [s / p if p else 0.0 for s, p in zip(sums, pulls)]
-        arm = select_index(pulls, means, total, budget, True)
-        pulls[arm] += 1
-        sums[arm] += costs[arm]
-        total += 1
-    return min(c for c, p in zip(costs, pulls) if p)
-
-
-@settings(max_examples=50, deadline=None)
-@given(st.lists(st.floats(min_value=1.0, max_value=1e3,
-                          allow_nan=False, allow_infinity=False),
-                min_size=1, max_size=8),
-       st.integers(min_value=1, max_value=10))
-def test_bandit_best_found_monotone_in_budget(costs, budget):
-    """A bigger exploration budget can only find a better-or-equal
-    arm — the regret the compare gate tracks never grows with budget
-    on the same synthetic arms."""
-    steps = len(costs) + 12
-    assert (_best_found(costs, budget + 1, steps)
-            <= _best_found(costs, budget, steps))
 
 
 @settings(max_examples=50, deadline=None)
